@@ -47,7 +47,9 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models.params import init_params
+from repro.serve.kvcache import kv_block_size
 from repro.serve.server import (ContinuousBatchServer, PagedBatchServer,
                                 StaticBatchServer)
 
@@ -150,8 +152,8 @@ def _run_paged(cfg, params, *, slots, max_prompt, max_new, precision,
     """Paged-pool axis: contiguous vs paged engine on a shared-prefix
     mixed-length workload (same requests, token-exactness asserted).
 
-    The paged server runs with block_size 8 (fine-grained pooling so
-    the tiny bench actually exercises tables/sharing) and a pool of
+    The paged server runs with the kernel's own block size
+    (``kv_block_size`` of the slot capacity) and a pool of
     ``pool_frac`` × the contiguous rectangle's blocks — under 1.0 the
     engine must preempt-and-recompute to stay correct, which the report
     counts.  Reported: tokens/s both engines, pool utilization (live /
@@ -166,7 +168,7 @@ def _run_paged(cfg, params, *, slots, max_prompt, max_new, precision,
     cont.submit(prompts, max_new_tokens=budgets)
     m_cont = cont.run()
 
-    bs = 8
+    bs = kv_block_size(cont.capacity)
     n_rect = slots * cont.capacity // bs
     pool = max(int(pool_frac * n_rect), cont.capacity // bs)
     paged = PagedBatchServer(
@@ -354,6 +356,7 @@ def main(argv=None) -> None:
     ap.add_argument("--tiny", action="store_true",
                     help="smoke-sized run for scripts/smoke.sh")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.tiny:
         args.requests, args.slots = 6, 2
         args.max_prompt, args.max_new = 16, 8

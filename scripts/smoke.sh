@@ -35,8 +35,10 @@ echo "=== paged KV serving (block tables, prefix reuse, preemption) ==="
 # force preempt-and-recompute (the summary line reports preemptions ≥ 1,
 # prefix-hit rate, and live-KV HBM vs the contiguous rectangle);
 # token-exactness vs the contiguous engine is asserted inside the bench.
+# Pool blocks are the kernel's 128-row tile, so prompts and budgets are
+# long enough for a slot to span several blocks.
 REPRO_KERNEL_PATH=interpret python benchmarks/serve_bench.py \
-    --requests 6 --slots 3 --max-prompt 24 --max-new 24 \
+    --requests 6 --slots 3 --max-prompt 160 --max-new 120 \
     --precision int8 --paged-only --pool-frac 0.34
 
 echo

@@ -25,14 +25,6 @@ import numpy as np
 from jax import export as jax_export
 
 
-def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` returns a dict on some jax versions
-    and a per-device list of dicts on others; normalize to one dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return cost or {}
-
-
 @dataclasses.dataclass
 class CompiledArtifact:
     name: str
@@ -71,7 +63,7 @@ def compile_fn(fn: Callable, *abstract_args, name: str = "fn",
     lowered = jfn.lower(*abstract_args)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     dt = time.time() - t0
     return CompiledArtifact(
         name=name, serialized=blob, input_specs=abstract_args,

@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import export as jax_export
 
 
 # ---------------------------------------------------------------------------
@@ -94,14 +95,10 @@ class Int8KV(NamedTuple):
 
 # jax.export serializes pytree defs by name: register both quantized
 # containers so int8 decode steps round-trip as CompiledArtifacts.
-try:
-    from jax import export as _jax_export
-    _jax_export.register_namedtuple_serialization(
-        QTensor, serialized_name="repro.quantize.QTensor")
-    _jax_export.register_namedtuple_serialization(
-        Int8KV, serialized_name="repro.quantize.Int8KV")
-except (ImportError, AttributeError):  # pragma: no cover - older jax
-    pass
+jax_export.register_namedtuple_serialization(
+    QTensor, serialized_name="repro.quantize.QTensor")
+jax_export.register_namedtuple_serialization(
+    Int8KV, serialized_name="repro.quantize.Int8KV")
 
 
 @dataclasses.dataclass
@@ -253,9 +250,13 @@ def maybe_quant_kv(policy: Optional[PrecisionPolicy], x: jax.Array):
 QUANT_SCOPES = ("attn", "mlp", "xattn")
 
 
+@jax.jit
 def _leaf_qtensor(w: jax.Array) -> QTensor:
     """Per-output-channel symmetric int8 over the contraction axis (-2),
-    keeping per-layer scales for stacked (L, K, N) leaves."""
+    keeping per-layer scales for stacked (L, K, N) leaves.  Jitted so
+    the elementwise chain fuses: run op by op it would hold several
+    f32 copies of the leaf (1.5 GiB each for internlm2-1.8b's stacked
+    MLP weights) beside the float model being quantized."""
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2)
     scale = jnp.maximum(amax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(w / scale[..., None, :]), -127, 127)
@@ -265,11 +266,14 @@ def _leaf_qtensor(w: jax.Array) -> QTensor:
 def quantize_model_params(params, policy: PrecisionPolicy = INT8):
     """Wrap every projection weight consumed by ``ops.quant_matmul`` in a
     ``QTensor``.  Leaves outside QUANT_SCOPES (embeddings, norms, MoE
-    banks, SSM dynamics) pass through untouched."""
+    banks, SSM dynamics) pass through untouched, and so do weights that
+    are already ``QTensor``s: quantizing twice is quantizing once."""
     if policy.weights != "int8":
         return params
 
     def wrap(path, leaf):
+        if isinstance(leaf, QTensor):
+            return leaf
         in_scope = any(getattr(k, "key", None) in QUANT_SCOPES
                        for k in path)
         if (in_scope and leaf.ndim >= 2
@@ -277,7 +281,8 @@ def quantize_model_params(params, policy: PrecisionPolicy = INT8):
             return _leaf_qtensor(leaf)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(wrap, params)
+    return jax.tree_util.tree_map_with_path(
+        wrap, params, is_leaf=lambda x: isinstance(x, QTensor))
 
 
 def attach_act_amax(qparams, amax_by_scope: Dict[str, float]):

@@ -3,24 +3,23 @@ the slot-addressed KV cache.
 
 One-token decode attention for the serving tier: every generated token
 streams the KV cache exactly once, in its stored precision.  Grid is
-(slot, kv-head, kv-block) with the KV sweep innermost so the online-
-softmax running state (max, sum, acc) lives in VMEM scratch across the
-blocks of one (slot, kv-head) pair.
+(slot, query-row tile, kv-block) with the KV sweep innermost, so the
+online-softmax running state (max, sum, acc) of every kv head lives in
+VMEM scratch across the blocks of one slot.
 
 Three things distinguish this from the prefill flash kernel:
 
-* **Grouped-query GQA in-kernel** — the q tile is the (G, D) group of
-  query heads sharing one KV head, so KV is never repeated (repeating a
-  slot cache costs G× its HBM bytes; see ``layers.decode_attention``'s
-  history).
+* **Grouped-query GQA in-kernel** — the q tile is the (Hkv, G, D)
+  grouping of the query heads, and each KV head's (bk, D) slab is
+  attended by its G query rows, so KV is never repeated (repeating a
+  slot cache costs G× its HBM bytes).
 * **Per-slot KV-length bounding** — ``kv_len (B,)`` is each slot's
   high-water mark (entries at index >= kv_len are guaranteed invalid,
   position −1).  Blocks entirely past it are skipped: their compute is
   predicated off AND their index map is clamped to the last live block,
-  so the pipeline elides the HBM→VMEM copy.  Capacity is sized for
-  ``max_prompt + max_new_cap`` but typical requests fill a fraction of
-  it; decode HBM traffic tracks actual occupancy, not capacity — and
-  with pad-free chunked admission the fill is exactly the live tokens.
+  so the pipeline elides the HBM→VMEM copy.  Decode HBM traffic tracks
+  actual occupancy, not capacity — and with pad-free chunked admission
+  the fill is exactly the live tokens.
 * **Fused Int8KV dequant** — int8 values and their per-(entry, head)
   f32 scales are read and dequantized inside the VMEM tile; decode never
   materializes a float copy of the cache.
@@ -32,19 +31,27 @@ positions −1) produces zeros, matching ``ref.decode_attention_ref``.
 
 ``flash_chunk_prefill`` is the C-query sibling serving chunked pad-free
 admission: the q tile carries the whole chunk's grouped query rows
-(C × G), per-row query positions ride in a VMEM operand (causality
-across the chunk is pure position masking — the chunk's KV is already
-in the cache), and the kv_len bounding / in-tile Int8KV dequant are
-shared with the decode kernel.
+(C × G) with per-row query positions (causality across the chunk is
+pure position masking — the chunk's KV is already in the cache).
+Decode is its C == 1 case: both run the one kernel below.
 
-Both kernels additionally speak the **paged pool** layout
-(docs/paged_kv.md): with a ``block_table`` (B, n_blocks) scalar-prefetch
-operand, k/v become an (NB, BS, Hkv, D) pool of fixed-size blocks and
-the grid's KV-block index resolves through the slot's table row inside
-the index maps — the DMA stream touches exactly the slot's blocks, the
-kv_len clamp/skip logic is unchanged, and ``kv_block_size`` (the tile
-helper shared with serve/kvcache.py) guarantees pool block == kernel
-block.
+**Tiling.**  Mosaic requires the last two dims of every block to be
+(8, 128)-divisible or whole.  A KV block therefore carries *all* kv
+heads, ``(1, bk, Hkv, D)`` — whole trailing dims, one contiguous DMA
+per block — and the kernel slices head ``h`` out of the VMEM tile.
+Positions ride as ``(…, 1, bk)`` rows and Int8KV scales as
+``(…, bk, Hkv)`` blocks, both whole in their trailing dims.
+
+Both kernels speak the **paged pool** layout (docs/paged_kv.md): with a
+``block_table`` (B, n_blocks) scalar-prefetch operand, k/v are an
+(NB, BS, Hkv, D) pool of fixed-size blocks and the grid's KV-block index
+resolves through the slot's table row inside the index maps — the DMA
+stream touches exactly the slot's blocks.  The contiguous (B, S, Hkv, D)
+layout is the same kernel over a pool of B·S/bk blocks addressed by an
+iota table, so there is one addressing path.  ``kv_block_size`` (the
+tile helper shared with serve/kvcache.py) guarantees pool block ==
+kernel block, and ``check_kv_block`` states which blocks the chip's
+compiler accepts.
 """
 from __future__ import annotations
 
@@ -57,9 +64,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# The kernels' default KV tile (rows per grid step): a full 128-lane
+# row of positions, and the block the serving engines round capacity to.
+BLOCK_K = 128
+# Query-row tile budget: the q/out tiles (double-buffered), the f32
+# accumulator and the lane-padded running max/sum together hold about
+# seven (Hkv, tq, D) f32-equivalents of VMEM; 2^19 elements keeps that
+# inside the 16 MiB of VMEM a v5e kernel gets by default.
+_Q_TILE_ELEMS = 1 << 19
 
 
-def kv_block_size(capacity: int, block_k: int = 128) -> int:
+def kv_block_size(capacity: int, block_k: int = BLOCK_K) -> int:
     """KV block granularity at a given per-slot capacity: the flash
     kernels' tile choice — min(block_k, capacity), halved until it
     divides capacity cleanly (floored at 8).  This is the single source
@@ -73,12 +88,24 @@ def kv_block_size(capacity: int, block_k: int = 128) -> int:
     return bk
 
 
-def _kernel(qp_ref, kl_ref, *refs,
-            scale: float, bk: int, n_k: int, window: int, int8: bool,
-            paged: bool):
-    if paged:
-        _tbl_ref, *refs = refs          # consumed by the index maps only
-    q_ref, k_ref, v_ref, pos_ref, *rest = refs
+def check_kv_block(block: int, capacity: int) -> None:
+    """Raise unless ``block`` can be the paged pool's block at this
+    per-slot ``capacity``.  Every operand block of the kernel is whole
+    in its trailing two dims — KV ``(1, bk, Hkv, D)``, Int8KV scales
+    ``(1, bk, Hkv)``, positions ``(1, 1, bk)`` — so Mosaic's (8, 128)
+    rule holds for any block size and KV dtype (float, bf16 or int8;
+    ``tests/test_tpu_compile.py`` compiles both ends of the range for a
+    v5e).  What remains is the block table's: a slot's capacity must be
+    a whole number of blocks."""
+    if block < 1 or capacity % block:
+        raise ValueError(f"KV block of {block} rows must be >= 1 and "
+                         f"divide the slot capacity {capacity}")
+
+
+def _kernel(kl_ref, tbl_ref, qp_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
+            scale: float, bk: int, n_k: int, hkv: int, window: int,
+            int8: bool):
+    del tbl_ref                          # consumed by the index maps only
     if int8:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -98,40 +125,44 @@ def _kernel(qp_ref, kl_ref, *refs,
     # are invalid, so blocks past the high-water mark contribute nothing.
     @pl.when(ki * bk < kvl)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, D)
-        if int8:
-            k = k * ks_ref[0].astype(jnp.float32)            # (bk, 1) scales
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = pos_ref[...]                                   # (1, bk) int32
-        qp = qp_ref[bi]
+        pos = pos_ref[0]                                     # (1, bk)
+        qp = qp_ref[0]                                       # (R, 1)
         idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        valid = (pos >= 0) & (pos <= qp) & (idx < kvl)
+        # pad query rows (qp == −1) have no valid key: pos >= 0 and
+        # pos <= −1 can't both hold, so they finalize to exact zeros.
+        valid = (pos >= 0) & (pos <= qp) & (idx < kvl)       # (R, bk)
         if window > 0:
             valid &= pos > qp - window
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit mask multiply: an all-invalid block has m_new == NEG_INF
-        # and exp(s - m_new) == 1 there — the mask zeroes it so empty
-        # slots finalize to exactly 0 instead of a garbage mean.
-        p = jnp.exp(s - m_new[:, None]) * valid.astype(jnp.float32)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if int8:
-            v = v * vs_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        keep = valid.astype(jnp.float32)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32) * scale      # (R, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # (bk, D)
+            if int8:
+                k = k * ks_ref[0, :, h:h + 1]                # (bk, 1) scales
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h]                                # (R, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # explicit mask multiply: an all-invalid block has m_new ==
+            # NEG_INF and exp(s - m_new) == 1 there — the mask zeroes it
+            # so empty rows finalize to exactly 0 instead of a garbage
+            # mean.
+            p = jnp.exp(s - m_new) * keep
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=1, keepdims=True)
+            m_ref[h] = m_new
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if int8:
+                v = v * vs_ref[0, :, h:h + 1]
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * alpha + pv
 
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _pad_seq(x: Optional[jax.Array], pad: int, axis: int, value=0):
@@ -142,6 +173,101 @@ def _pad_seq(x: Optional[jax.Array], pad: int, axis: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _as_blocks(x: Optional[jax.Array], bk: int) -> Optional[jax.Array]:
+    """(B, S, ...) slot rows -> (B·S/bk, bk, ...) pool blocks (a reshape
+    of major dims: no copy)."""
+    if x is None:
+        return None
+    return x.reshape((-1, bk) + x.shape[2:])
+
+
+def _q_tile(r: int, hkv: int, d: int) -> int:
+    """Query rows per grid step: all R rows while the (Hkv, R, D) tiles
+    fit the VMEM budget, else the largest multiple of 8 that divides R
+    and fits.  Each extra row tile re-reads the slot's KV blocks."""
+    lanes = -(-d // 128) * 128
+    cap = _Q_TILE_ELEMS // (hkv * lanes)
+    if r <= cap:
+        return r
+    for tq in range(cap - cap % 8, 7, -8):
+        if r % tq == 0:
+            return tq
+    return r
+
+
+def _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
+            block_table, window, block_k, interpret):
+    """The one pallas_call behind both entry points.  q: (B, Hkv, R, D)
+    grouped query rows; q_pos: (B, R) per-row positions."""
+    b, hkv, r, d = q.shape
+    if block_table is None:
+        # contiguous slot rows == a pool of B·n_k blocks whose table is
+        # an iota.  Prefer a block that divides S (halving down to 8)
+        # over padding — padding copies the cache once per call.
+        s = k.shape[1]
+        bk = kv_block_size(s, block_k)
+        pad = (-s) % bk
+        k, v, k_scale, v_scale = (_as_blocks(_pad_seq(x, pad, 1), bk)
+                                  for x in (k, v, k_scale, v_scale))
+        cache_pos = _pad_seq(cache_pos, pad, 1, value=-1)
+        n_k = (s + pad) // bk
+        block_table = jnp.arange(b * n_k, dtype=jnp.int32).reshape(b, n_k)
+    else:
+        # pool block == kernel KV block by construction (kv_block_size)
+        bk = k.shape[1]
+        n_k = block_table.shape[1]
+    int8 = k_scale is not None
+    tq = _q_tile(r, hkv, d)
+
+    def blk(bi, ki, kl, tbl):
+        # Dead blocks re-map to the last live one: an unchanged block
+        # index means the pipeline skips the HBM→VMEM copy entirely.
+        last_live = jnp.maximum(pl.cdiv(kl[bi], bk) - 1, 0)
+        return tbl[bi, jnp.minimum(ki, last_live)]
+
+    def row_index(bi, qi, ki, kl, tbl):
+        return (bi, 0, qi, 0)
+
+    def kv_index(bi, qi, ki, kl, tbl):
+        return (blk(bi, ki, kl, tbl), 0, 0, 0)
+
+    def vec_index(bi, qi, ki, kl, tbl):
+        return (blk(bi, ki, kl, tbl), 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, tq, 1), lambda bi, qi, ki, kl, tbl: (bi, qi, 0)),
+        pl.BlockSpec((1, hkv, tq, d), row_index),
+        pl.BlockSpec((1, bk, hkv, d), kv_index),
+        pl.BlockSpec((1, bk, hkv, d), kv_index),
+        pl.BlockSpec((1, 1, bk), vec_index),
+    ]
+    operands = [q_pos.astype(jnp.int32)[:, :, None], q, k, v,
+                cache_pos.reshape(-1, 1, bk)]
+    if int8:
+        in_specs += [pl.BlockSpec((1, bk, hkv), vec_index)] * 2
+        operands += [k_scale, v_scale]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, r // tq, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hkv, tq, d), row_index),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, tq, 1), jnp.float32),    # running max
+            pltpu.VMEM((hkv, tq, 1), jnp.float32),    # running sum
+            pltpu.VMEM((hkv, tq, d), jnp.float32),    # output accumulator
+        ])
+    kernel = functools.partial(
+        _kernel, scale=d ** -0.5, bk=bk, n_k=n_k, hkv=hkv, window=window,
+        int8=int8)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
+        interpret=interpret,
+    )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32), *operands)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("window", "block_k", "interpret"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -149,7 +275,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  *, k_scale: Optional[jax.Array] = None,
                  v_scale: Optional[jax.Array] = None,
                  block_table: Optional[jax.Array] = None,
-                 window: int = 0, block_k: int = 128,
+                 window: int = 0, block_k: int = BLOCK_K,
                  interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, G, D) grouped queries.
 
@@ -179,154 +305,10 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     paged layout the kernel block IS the pool block (``kv_block_size``),
     so no shrink/pad path exists.
     """
-    b, hkv, g, d = q.shape
-    paged = block_table is not None
-    if paged:
-        # pool block == kernel KV block by construction (kv_block_size)
-        bk = k.shape[1]
-        n_k = block_table.shape[1]
-        pad = 0
-    else:
-        s = k.shape[1]
-        # prefer a block that divides S (halving down to 8) over padding —
-        # padding copies the cache once per call
-        bk = kv_block_size(s, block_k)
-        pad = (-s) % bk
-        if pad:
-            k = _pad_seq(k, pad, 1)
-            v = _pad_seq(v, pad, 1)
-            k_scale = _pad_seq(k_scale, pad, 1)
-            v_scale = _pad_seq(v_scale, pad, 1)
-            cache_pos = _pad_seq(cache_pos, pad, 1, value=-1)
-        n_k = (s + pad) // bk
-    int8 = k_scale is not None
-
-    def _clamp(bi, ki, kl):
-        # Dead blocks re-map to the last live one: an unchanged block
-        # index means the pipeline skips the HBM→VMEM copy entirely.
-        last_live = jnp.maximum(pl.cdiv(kl[bi], bk) - 1, 0)
-        return jnp.minimum(ki, last_live)
-
-    if paged:
-        def q_index(bi, hi, ki, qp, kl, tbl):
-            return (bi, hi, 0, 0)
-
-        def kv_index(bi, hi, ki, qp, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0, hi, 0)
-
-        def pos_index(bi, hi, ki, qp, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0)
-
-        def scale_index(bi, hi, ki, qp, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0, hi)
-    else:
-        def q_index(bi, hi, ki, qp, kl):
-            return (bi, hi, 0, 0)
-
-        def kv_index(bi, hi, ki, qp, kl):
-            return (bi, _clamp(bi, ki, kl), hi, 0)
-
-        def pos_index(bi, hi, ki, qp, kl):
-            return (bi, _clamp(bi, ki, kl))
-
-        def scale_index(bi, hi, ki, qp, kl):
-            return (bi, _clamp(bi, ki, kl), hi)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_index),
-        pl.BlockSpec((1, bk, 1, d), kv_index),
-        pl.BlockSpec((1, bk, 1, d), kv_index),
-        pl.BlockSpec((1, bk), pos_index),
-    ]
-    operands = [q, k, v, cache_pos]
-    if int8:
-        in_specs += [pl.BlockSpec((1, bk, 1), scale_index),
-                     pl.BlockSpec((1, bk, 1), scale_index)]
-        operands += [k_scale, v_scale]
-
-    prefetch = [q_pos.astype(jnp.int32), kv_len.astype(jnp.int32)]
-    if paged:
-        prefetch.append(block_table.astype(jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(b, hkv, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),       # running max
-            pltpu.VMEM((g,), jnp.float32),       # running sum
-            pltpu.VMEM((g, d), jnp.float32),     # output accumulator
-        ])
-    kernel = functools.partial(
-        _kernel, scale=d ** -0.5, bk=bk, n_k=n_k, window=window, int8=int8,
-        paged=paged)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        interpret=interpret,
-    )(*prefetch, *operands)
-
-
-# ---------------------------------------------------------------------------
-# Chunk-prefill attention (C queries per slot, cache-resident KV)
-# ---------------------------------------------------------------------------
-def _chunk_kernel(kl_ref, *refs,
-                  scale: float, bk: int, n_k: int, window: int, int8: bool,
-                  paged: bool):
-    if paged:
-        _tbl_ref, *refs = refs          # consumed by the index maps only
-    qp_ref, q_ref, k_ref, v_ref, pos_ref, *rest = refs
-    if int8:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    bi = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kvl = kl_ref[bi]
-
-    @pl.when(ki * bk < kvl)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (R, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, D)
-        if int8:
-            k = k * ks_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = pos_ref[...]                                   # (1, bk) int32
-        qp = qp_ref[0][:, None]                              # (R, 1) int32
-        idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        # pad query rows (qp == −1) have no valid key: pos >= 0 and
-        # pos <= −1 can't both hold, so they finalize to exact zeros.
-        valid = (pos >= 0) & (pos <= qp) & (idx < kvl)
-        if window > 0:
-            valid &= pos > qp - window
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None]) * valid.astype(jnp.float32)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if int8:
-            v = v * vs_ref[0].astype(jnp.float32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-
-    @pl.when(ki == n_k - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    b, _, g, _ = q.shape
+    q_rows = jnp.broadcast_to(q_pos.astype(jnp.int32)[:, None], (b, g))
+    return _attend(q, q_rows, k, v, cache_pos, kv_len, k_scale, v_scale,
+                   block_table, window, block_k, interpret)
 
 
 @functools.partial(jax.jit,
@@ -337,7 +319,7 @@ def flash_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                         *, k_scale: Optional[jax.Array] = None,
                         v_scale: Optional[jax.Array] = None,
                         block_table: Optional[jax.Array] = None,
-                        window: int = 0, block_k: int = 128,
+                        window: int = 0, block_k: int = BLOCK_K,
                         interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, R, D) grouped chunk queries — R = C·G rows ordered
     (query, group), i.e. row ``c*G + g``; q_pos: (B, R) per-row absolute
@@ -357,91 +339,5 @@ def flash_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     its rows, or concatenated for ring layouts): in-chunk causality is
     decided purely by ``pos <= q_pos``, identical to the decode kernel.
     """
-    b, hkv, r, d = q.shape
-    paged = block_table is not None
-    if paged:
-        bk = k.shape[1]
-        n_k = block_table.shape[1]
-    else:
-        s = k.shape[1]
-        bk = kv_block_size(s, block_k)
-        pad = (-s) % bk
-        if pad:
-            k = _pad_seq(k, pad, 1)
-            v = _pad_seq(v, pad, 1)
-            k_scale = _pad_seq(k_scale, pad, 1)
-            v_scale = _pad_seq(v_scale, pad, 1)
-            cache_pos = _pad_seq(cache_pos, pad, 1, value=-1)
-        n_k = (s + pad) // bk
-    int8 = k_scale is not None
-
-    def _clamp(bi, ki, kl):
-        last_live = jnp.maximum(pl.cdiv(kl[bi], bk) - 1, 0)
-        return jnp.minimum(ki, last_live)
-
-    if paged:
-        def q_index(bi, hi, ki, kl, tbl):
-            return (bi, hi, 0, 0)
-
-        def qp_index(bi, hi, ki, kl, tbl):
-            return (bi, 0)
-
-        def kv_index(bi, hi, ki, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0, hi, 0)
-
-        def pos_index(bi, hi, ki, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0)
-
-        def scale_index(bi, hi, ki, kl, tbl):
-            return (tbl[bi, _clamp(bi, ki, kl)], 0, hi)
-    else:
-        def q_index(bi, hi, ki, kl):
-            return (bi, hi, 0, 0)
-
-        def qp_index(bi, hi, ki, kl):
-            return (bi, 0)
-
-        def kv_index(bi, hi, ki, kl):
-            return (bi, _clamp(bi, ki, kl), hi, 0)
-
-        def pos_index(bi, hi, ki, kl):
-            return (bi, _clamp(bi, ki, kl))
-
-        def scale_index(bi, hi, ki, kl):
-            return (bi, _clamp(bi, ki, kl), hi)
-
-    in_specs = [
-        pl.BlockSpec((1, r), qp_index),
-        pl.BlockSpec((1, 1, r, d), q_index),
-        pl.BlockSpec((1, bk, 1, d), kv_index),
-        pl.BlockSpec((1, bk, 1, d), kv_index),
-        pl.BlockSpec((1, bk), pos_index),
-    ]
-    operands = [q_pos.astype(jnp.int32), q, k, v, cache_pos]
-    if int8:
-        in_specs += [pl.BlockSpec((1, bk, 1), scale_index),
-                     pl.BlockSpec((1, bk, 1), scale_index)]
-        operands += [k_scale, v_scale]
-
-    prefetch = [kv_len.astype(jnp.int32)]
-    if paged:
-        prefetch.append(block_table.astype(jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(b, hkv, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, r, d), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((r,), jnp.float32),       # running max
-            pltpu.VMEM((r,), jnp.float32),       # running sum
-            pltpu.VMEM((r, d), jnp.float32),     # output accumulator
-        ])
-    kernel = functools.partial(
-        _chunk_kernel, scale=d ** -0.5, bk=bk, n_k=n_k, window=window,
-        int8=int8, paged=paged)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
-        interpret=interpret,
-    )(*prefetch, *operands)
+    return _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
+                   block_table, window, block_k, interpret)

@@ -33,7 +33,7 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
 
     @pl.when(k_idx == n_k - 1)
     def _epilogue():
-        scale = xs_ref[...][:, None] * ws_ref[...][None, :]
+        scale = xs_ref[...] * ws_ref[...]                   # (bm, 1)·(1, bn)
         o_ref[...] = acc_ref[...].astype(jnp.float32) * scale
 
 
@@ -69,6 +69,10 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         x_scale = jnp.pad(x_scale, (0, mp - m))
         w_scale = jnp.pad(w_scale, (0, np_ - n))
     n_k = kp // bk
+    # scales ride as a (Mp, 1) column and a (1, Np) row: 2-D blocks whose
+    # trailing dims Mosaic tiles like XLA does (a 1-D block does not)
+    x_scale = x_scale.reshape(mp, 1)
+    w_scale = w_scale.reshape(1, np_)
 
     grid = (mp // bm, np_ // bn, n_k)
     out = pl.pallas_call(
@@ -77,8 +81,8 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),

@@ -30,18 +30,11 @@ from repro.kernels import mel_frontend as mf
 from repro.kernels import ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def resolve_path(force: Optional[str] = None) -> str:
     """Backend for one kernel dispatch: per-call force > flags pin >
     default probe (pallas on TPU, ref elsewhere)."""
-    return (force or flags.get("kernel_path")
-            or ("pallas" if _on_tpu() else "ref"))
+    on_tpu = jax.default_backend() == "tpu"
+    return force or flags.get("kernel_path") or ("pallas" if on_tpu else "ref")
 
 
 def int8_matmul(x_q, w_q, x_scale, w_scale, *, force: Optional[str] = None):

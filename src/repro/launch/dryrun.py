@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from repro import configs, flags as perf_flags
 from repro.core.arch import SHAPES, ArchConfig, ShapeConfig, shape_applicable
-from repro.core.eon_compiler import normalize_cost_analysis
 from repro.launch.mesh import make_production_mesh, mesh_name
 from repro.models import api
 from repro.models.params import abstract_params, logical_axes, param_count
@@ -176,7 +175,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     wc = analyze_module(hlo)   # loop-weighted (cost_analysis is not)
     colls = {k: dict(v) for k, v in wc.collectives.items()}

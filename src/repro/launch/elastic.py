@@ -14,12 +14,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit/auto axis types on meshes
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.sharding.policy import AxisRules, params_pspecs
@@ -64,8 +59,6 @@ def plan_rescale(mesh_shape: Dict[str, int], surviving_devices: int,
 def build_mesh(shape: Dict[str, int]) -> Mesh:
     axes = tuple(shape.keys())
     dims = tuple(shape.values())
-    if AxisType is None:
-        return jax.make_mesh(dims, axes)
     return jax.make_mesh(dims, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

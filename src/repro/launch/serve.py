@@ -1,9 +1,12 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id>``.
 
 Runs the continuous-batching server over synthetic prompts on the
-selected arch (smoke config on CPU; same code takes the full config on
-a pod).  ``--engine static`` selects the static-batching baseline,
-``--engine paged`` the paged-KV-pool engine (block tables, prefix
+selected arch: its smoke config by default, its published widths with
+``--full`` (which needs the TPU: ``chip_smoke.py`` drives the paged
+engine that way on one v5e chip).  Kernels run through Pallas on a TPU
+backend and through the jnp references elsewhere.  ``--engine static``
+selects the static-batching baseline, ``--engine paged`` the
+paged-KV-pool engine (block tables, prefix
 sharing, preempt-and-recompute — docs/paged_kv.md; ``--pool-blocks``
 sizes the pool below the contiguous rectangle), ``--artifact`` runs the
 decode hot loop from an AOT ``CompiledArtifact`` (paper C4: serve the
@@ -18,6 +21,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models.params import init_params
 from repro.serve.server import (ContinuousBatchServer, PagedBatchServer,
                                 StaticBatchServer)
@@ -46,6 +50,7 @@ def main() -> None:
                          " + Int8KV cache (paper C5 end-to-end)")
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get(args.arch) if args.full else configs.get_smoke(args.arch)
     params = init_params(cfg, jax.random.key(0))

@@ -18,6 +18,7 @@ import numpy as np
 from repro import configs
 from repro.core.arch import ShapeConfig
 from repro.data.synthetic import lm_batches, token_stream
+from repro.launch import compile_cache
 from repro.models.params import init_params, param_count
 from repro.train.optimizer import AdamWConfig, adamw_init
 from repro.train.train_step import make_train_step
@@ -53,6 +54,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg, params, opt_state, step = build(
         args.arch, smoke=args.smoke, batch=args.batch, seq=args.seq,

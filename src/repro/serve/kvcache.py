@@ -62,7 +62,7 @@ from repro.core.arch import ArchConfig, ShapeConfig
 from repro.core.quantize import PrecisionPolicy
 # canonical block-granularity helper (defined next to the kernels it
 # must agree with; this module is its serving-side home)
-from repro.kernels.flash_decode import kv_block_size  # noqa: F401
+from repro.kernels.flash_decode import check_kv_block, kv_block_size
 
 
 def kv_cache_bytes(cfg: ArchConfig, batch: int, seq_len: int,
@@ -241,11 +241,11 @@ def abstract_paged_cache(cfg: ArchConfig, slots: int, capacity: int,
     ``local_pos``) as the usual ``slots``-row slot leaves.  BS defaults
     to ``kv_block_size(capacity)`` (the kernel tile — maximum DMA
     efficiency) and may be overridden by any divisor of ``capacity``
-    that still tiles (≥ 8) for finer-grained pooling; the block table
-    itself is host state (a (slots, capacity // BS) int32 operand, not
-    a cache leaf)."""
+    the kernel accepts (``check_kv_block``) for finer-grained pooling;
+    the block table itself is host state (a (slots, capacity // BS)
+    int32 operand, not a cache leaf)."""
     bs = block_size or kv_block_size(capacity)
-    assert capacity % bs == 0 and bs >= 8, (capacity, bs)
+    check_kv_block(bs, capacity)
     slot_abs = abstract_decode_cache(cfg, slots, capacity, policy)
     keys = paged_cache_keys(cfg)
     cache = {k: v for k, v in slot_abs.items()

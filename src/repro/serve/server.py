@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.core.arch import ArchConfig
 from repro.core.quantize import policy_for, quantize_model_params
+from repro.kernels.flash_decode import BLOCK_K, check_kv_block
 from repro.serve.kvcache import (BlockManager, PoolExhausted,
                                  alloc_decode_cache, alloc_paged_cache,
                                  decode_cache_nbytes, kv_block_size,
@@ -70,13 +71,6 @@ from repro.serve.serve_step import (make_chunk_prefill_step,
                                     make_paged_chunk_prefill_step,
                                     make_paged_decode_step,
                                     make_slot_decode_step)
-
-# Decode-cache capacity granularity: one flash-decode KV block — the
-# kernels' tile choice at any rounded capacity is kv_block_size(), and
-# rounding capacity to this keeps that choice at its maximum on every
-# backend (kvcache.kv_block_size is the single source of truth).
-KV_BLOCK = 64
-
 
 @dataclasses.dataclass
 class Request:
@@ -148,12 +142,13 @@ class _ServerBase:
     def _slot_capacity(self) -> int:
         """Per-slot KV rows: prompt + generation budget, with headroom
         for a ragged final chunk's pad tail at max_prompt, rounded up to
-        the flash-decode KV block so the kernel never pads the cache per
-        step; the tail is dead capacity the per-slot kv_len bound skips
-        without reading.  Both engines and ``_check_fits`` share this."""
+        the flash-decode KV tile (``BLOCK_K``) so the kernel runs at its
+        full tile and never pads the cache per step; the tail is dead
+        capacity the per-slot kv_len bound skips without reading.  Both
+        engines and ``_check_fits`` share this."""
         need = max(self.max_prompt + self.max_new_cap,
                    _chunk_rows(self.max_prompt, self.chunk))
-        return -(-need // KV_BLOCK) * KV_BLOCK
+        return -(-need // BLOCK_K) * BLOCK_K
 
     def _init_slot_steps(self, n_slots: int) -> None:
         """Chunk-prefill / decode / reset steps over an ``n_slots`` ×
@@ -583,14 +578,11 @@ class PagedBatchServer(_ServerBase):
         self.max_new_cap = int(max_new_cap or max(self.max_new, 1))
         self.capacity = self._slot_capacity()
         # pool block: the kernel tile by default (maximum DMA width);
-        # any smaller divisor of capacity (≥ 8, still tileable) trades
-        # DMA width for allocation granularity / prefix-hit resolution
+        # any smaller divisor of capacity the kernel accepts trades DMA
+        # width for allocation granularity / prefix-hit resolution
         self.block_size = self._kv_block = int(
             block_size or kv_block_size(self.capacity))
-        if self.capacity % self.block_size or self.block_size < 8:
-            raise ValueError(
-                f"block_size {self.block_size} must divide capacity "
-                f"{self.capacity} and be >= 8")
+        check_kv_block(self.block_size, self.capacity)
         if self.capacity % self.chunk:
             raise ValueError(
                 f"prefill_chunk {self.chunk} must divide the rounded "

@@ -108,6 +108,36 @@ def test_chunk_attention_c1_matches_decode(force):
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("precision", ["float", "int8"])
+def test_chunk_attention_row_tiles_match_ref(precision, monkeypatch):
+    """When the (Hkv, rows, D) query tiles outgrow the kernel's VMEM
+    budget the rows split into tiles, each sweeping the KV blocks anew;
+    shrink the budget so 40 rows run as five tiles of 8."""
+    from repro.kernels import flash_decode as fd
+    monkeypatch.setattr(fd, "_Q_TILE_ELEMS", 2 * 8 * 128)
+    rng = np.random.RandomState(3)
+    b, c, s, hq, hkv, d = 2, 20, 24, 4, 2, 16
+    q, k, v, qpos, pos, kvl = _chunk_case(
+        rng, b, c, s, hq, hkv, d, fills=[20, 24], reals=[17, 20])
+    k_scale = v_scale = None
+    if precision == "int8":
+        k, v = qz.quant_kv(k), qz.quant_kv(v)
+        k, k_scale, v, v_scale = k.q, k.scale, v.q, v.scale
+    g = hq // hkv
+    assert fd._q_tile(c * g, hkv, d) == 8
+    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, hkv, c * g, d)
+    rows = jnp.repeat(qpos, g, axis=1)
+    got = fd._attend(qg, rows, k, v, pos, kvl, k_scale, v_scale, None,
+                     window=0, block_k=fd.BLOCK_K, interpret=True)
+    got = got.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, c, hq, d)
+    if precision == "int8":
+        k, v = qz.Int8KV(k, k_scale), qz.Int8KV(v, v_scale)
+    want = ops.chunk_attention(q, k, v, qpos, pos, kv_len=kvl, force="ref")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
 def test_chunk_attention_kv_len_blocks_skipped():
     """Poison the cache beyond kv_len with attendable-looking entries:
     the chunk kernel must not read them (bound is a skip, not a mask)."""

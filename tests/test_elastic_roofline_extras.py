@@ -93,3 +93,25 @@ def test_dryrun_matrix_complete_on_disk():
     assert status.get("error", 0) == 0
     assert status.get("skipped", 0) == 14          # 7 archs × 2 meshes
     assert status.get("ok", 0) == 66
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """Entry points keep JAX's compile cache in $JAX_COMPILATION_CACHE_DIR
+    (setting no other directory) or else in the git-ignored
+    ``<repo>/.jax_cache``."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    repo = Path(compile_cache.__file__).resolve().parents[3]
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.enable() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", saved)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == saved
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()

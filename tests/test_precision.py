@@ -80,6 +80,17 @@ def test_quantize_model_params_scopes(setup):
     assert qz.quantize_model_params(params, qz.FLOAT) is params
 
 
+def test_quantize_model_params_is_idempotent(setup):
+    """Handing a server already-quantized weights (so the float ones can
+    be freed) serves the same weights: QTensor leaves pass through."""
+    _, params = setup
+    qp = qz.quantize_model_params(params, qz.INT8)
+    again = qz.quantize_model_params(qp, qz.INT8)
+    assert jax.tree.structure(again) == jax.tree.structure(qp)
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(qp)):
+        assert a is b
+
+
 def test_quantize_model_params_moe_banks_stay_float():
     cfg = configs.get_smoke("dbrx-132b")
     params = init_params(cfg, jax.random.key(1))

@@ -74,8 +74,7 @@ def test_analyzer_loop_weighting_exact():
     wc = analyze_module(comp.as_text())
     expect = 12 * (2 * 8 * 256 * 128 + 2 * 8 * 128 * 256)
     assert wc.flops == expect
-    from repro.core.eon_compiler import normalize_cost_analysis
-    raw = normalize_cost_analysis(comp.cost_analysis())
+    raw = comp.cost_analysis()
     assert raw["flops"] < expect         # the raw one undercounts
 
 
