@@ -5,9 +5,13 @@ Each factory takes an optional ``PrecisionPolicy`` (core/quantize):
 the step closes over it, so float and int8 servers lower distinct
 (but same-signature) executables.
 
-``decode_*`` shapes lower ``decode_step`` (one new token against a KV
-cache of seq_len), ``prefill_*`` shapes lower ``prefill_step`` — per the
-assignment's cell semantics.  ``chunk_prefill_step`` is the admission
+``decode_*`` shapes lower ``plain_decode_step`` (one new token against a
+KV cache of seq_len), ``prefill_*`` shapes lower ``prefill_step`` — per
+the assignment's cell semantics.  Each step function's name is its
+program's name once jitted (``jit_<name>`` in a profiler trace), so the
+names are unique: ``jit_decode_step`` is only the paged decode step and
+``jit_paged_chunk_step`` only the paged chunk step, the two programs
+the chip benchmark reads.  ``chunk_prefill_step`` is the admission
 path of chunked pad-free prefill: one fixed-size chunk of C prompt
 tokens against one slot's live cache row, compiled once per chunk shape
 (instead of once per padded bucket).
@@ -129,13 +133,13 @@ def make_decode_step(cfg: ArchConfig, *, rules: Optional[AxisRules] = None,
                      mesh=None, policy=None):
     fns = model_fns(cfg)
 
-    def decode_step(params, cache, token, position):
+    def plain_decode_step(params, cache, token, position):
         logits, new_cache = fns.forward_decode(cfg, params, cache, token,
                                                position, policy=policy)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_token, logits, new_cache
 
-    return _context(decode_step, rules, mesh)
+    return _context(plain_decode_step, rules, mesh)
 
 
 def make_slot_decode_step(cfg: ArchConfig, *,
@@ -160,14 +164,14 @@ def make_slot_decode_step(cfg: ArchConfig, *,
     """
     fns = model_fns(cfg)
 
-    def decode_step(params, cache, token, position, kv_len):
+    def slot_decode_step(params, cache, token, position, kv_len):
         logits, new_cache = fns.forward_decode(cfg, params, cache, token,
                                                position, policy=policy,
                                                kv_len=kv_len)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_token, logits, new_cache
 
-    return _context(decode_step, rules, mesh)
+    return _context(slot_decode_step, rules, mesh)
 
 
 def make_paged_decode_step(cfg: ArchConfig, *,

@@ -56,6 +56,7 @@ from typing import Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.arch import ArchConfig
 from repro.core.quantize import policy_for, quantize_model_params
@@ -82,6 +83,10 @@ class Request:
     done: bool = False
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    admitted_at: Optional[float] = None   # host clock at first admission
+    # host clock per emitted token: one stamp per decode step, taken
+    # after the token pull and shared by that step's tokens
+    token_times: List[float] = dataclasses.field(default_factory=list)
     admitted_step: Optional[int] = None   # decode-step clock at admission
     finished_step: Optional[int] = None
     preemptions: int = 0            # paged engine: times evicted/recomputed
@@ -210,11 +215,10 @@ class _ServerBase:
             reqs.append(r)
         return reqs
 
-    def _chunk_call(self, slot, toks, poss, kvl):
-        """Run one chunk step for ``slot`` (the paged engine overrides
-        this to append the slot's block-table row operand)."""
-        return self._chunk_step(self.params, self.cache, toks, poss,
-                                slot.index, kvl)
+    def _chunk_table(self, slot) -> tuple:
+        """Operands a chunk step takes after ``kv_len`` (the paged
+        engine's block-table row of ``slot``)."""
+        return ()
 
     def _register_prefill(self, slot, prompt) -> None:
         """Hook at prefill completion (paged: publish prefix blocks)."""
@@ -235,30 +239,58 @@ class _ServerBase:
         prompt = slot.prompt
         p = slot.chunk_pos
         r = min(c, len(prompt) - p)
-        toks = np.zeros((1, c), np.int32)
-        poss = np.full((1, c), -1, np.int32)
-        toks[0, :r] = prompt[p:p + r]
-        poss[0, :r] = np.arange(p, p + r, dtype=np.int32)
-        kvl = jnp.asarray([p + c], jnp.int32)
-        ntok, _, self.cache = self._chunk_call(
-            slot, jnp.asarray(toks), jnp.asarray(poss), kvl)
-        slot.chunk_pos += r
-        if slot.chunk_pos < len(prompt):
-            return
-        # final chunk: its last real row's logits are the next token
-        req = self.requests[slot.rid]
-        self._register_prefill(slot, prompt)
-        tok0 = int(np.asarray(ntok)[0, r - 1])
-        req.tokens.append(tok0)
-        if req.first_token_at is None:
-            req.first_token_at = time.perf_counter()
-        slot.begin_decode()
-        slot.generated = len(req.tokens)
-        if slot.generated >= slot.max_new or tok0 == self.eos_id:
-            self._finish(req, step_clock)
-            self._release_finished(slot)
-        else:
-            self._cur[slot.index] = tok0
+        with TraceAnnotation("serve.chunk", rid=slot.rid, pos=p, rows=r):
+            with TraceAnnotation("serve.chunk.prepare"):
+                toks = np.zeros((1, c), np.int32)
+                poss = np.full((1, c), -1, np.int32)
+                toks[0, :r] = prompt[p:p + r]
+                poss[0, :r] = np.arange(p, p + r, dtype=np.int32)
+                args = (jnp.asarray(toks), jnp.asarray(poss), slot.index,
+                        jnp.asarray([p + c], jnp.int32),
+                        *self._chunk_table(slot))
+            with TraceAnnotation("serve.chunk.launch"):
+                ntok, _, self.cache = self._chunk_step(self.params,
+                                                       self.cache, *args)
+            slot.chunk_pos += r
+            if slot.chunk_pos < len(prompt):
+                return
+            # final chunk: its last real row's logits are the next token
+            req = self.requests[slot.rid]
+            self._register_prefill(slot, prompt)
+            with TraceAnnotation("serve.chunk.pull"):
+                tok0 = int(np.asarray(ntok)[0, r - 1])
+            now = time.perf_counter()
+            req.tokens.append(tok0)
+            req.token_times.append(now)
+            if req.first_token_at is None:
+                req.first_token_at = now
+            slot.begin_decode()
+            slot.generated = len(req.tokens)
+            if slot.generated >= slot.max_new or tok0 == self.eos_id:
+                self._finish(req, step_clock)
+                self._release_finished(slot)
+            else:
+                self._cur[slot.index] = tok0
+
+    def _emit(self, active, ntok, step_clock: int) -> None:
+        """Append each active slot's token from the pulled decode output
+        ``ntok``, stamped with one host time for the step, then finish
+        and free the slots whose request is done."""
+        with TraceAnnotation("serve.decode.emit") as span:
+            now = time.perf_counter()
+            finished = 0
+            for s in active:
+                req = self.requests[s.rid]
+                t = int(ntok[s.index])
+                req.tokens.append(t)
+                req.token_times.append(now)
+                s.advance()
+                self._cur[s.index] = t
+                if s.generated >= s.max_new or t == self.eos_id:
+                    self._finish(req, step_clock)
+                    self._release_finished(s)
+                    finished += 1
+            span.set_metadata(finished=finished)
 
     def _finish(self, req: Request, step_clock: int) -> None:
         req.done = True
@@ -350,6 +382,7 @@ class ContinuousBatchServer(_ServerBase):
                                          slot.index)
                 slot.occupy(req.rid, req.prompt, req.max_new_tokens)
                 req.admitted_step = decode_steps
+                req.admitted_at = time.perf_counter()
 
             # Budgeted chunk prefill, oldest request first: at most
             # prefill_budget prompt tokens per decode step (always at
@@ -387,18 +420,7 @@ class ContinuousBatchServer(_ServerBase):
             blocks = np.maximum(-(-kvl // self._kv_block), 1)
             kv_fill.append(int(blocks.sum()) * self._kv_block)
             kv_raw.append(int(kvl.sum()))
-            ntok_h = np.asarray(ntok)
-
-            for s in active:
-                req = self.requests[s.rid]
-                t = int(ntok_h[s.index])
-                req.tokens.append(t)
-                s.advance()
-                self._cur[s.index] = t
-                if s.generated >= s.max_new or t == self.eos_id:
-                    self._finish(req, decode_steps)
-                    self.cache = self._release(self.cache, s.index)
-                    s.release()
+            self._emit(active, np.asarray(ntok), decode_steps)
 
         served = self._served
         wall = time.perf_counter() - t0
@@ -478,6 +500,7 @@ class StaticBatchServer(_ServerBase):
                 slot = Slot(i)
                 slot.occupy(r.rid, r.prompt, r.max_new_tokens)
                 r.admitted_step = decode_steps
+                r.admitted_at = time.perf_counter()
                 while slot.prefilling:      # full prefill, no interleave
                     self._run_chunk(slot, decode_steps)
                     prefill_chunks += 1
@@ -499,6 +522,7 @@ class StaticBatchServer(_ServerBase):
                                                   tok, pos, kvl)
                 decode_steps += 1
                 ntok_h = np.asarray(ntok)
+                now = time.perf_counter()
                 for s in slots:
                     if not s.active:
                         continue
@@ -508,6 +532,7 @@ class StaticBatchServer(_ServerBase):
                     self._cur[s.index] = t
                     if not r.done:
                         r.tokens.append(t)
+                        r.token_times.append(now)
                         if len(r.tokens) >= r.max_new_tokens:
                             self._finish(r, decode_steps)
 
@@ -678,9 +703,11 @@ class PagedBatchServer(_ServerBase):
     def submit(self, prompts: List[np.ndarray],
                max_new_tokens: Union[int, Sequence[int], None] = None
                ) -> List[Request]:
-        reqs = self._make_requests(prompts, max_new_tokens)
-        for r in reqs:
-            self.sched.enqueue(r)
+        with TraceAnnotation("serve.submit", rid=self._next_rid,
+                             n=len(prompts)):
+            reqs = self._make_requests(prompts, max_new_tokens)
+            for r in reqs:
+                self.sched.enqueue(r)
         return reqs
 
     # ------------------------------------------------------------------
@@ -776,12 +803,10 @@ class PagedBatchServer(_ServerBase):
             self._set_table_row(slot)
             if req.admitted_step is None:
                 req.admitted_step = decode_steps
+                req.admitted_at = time.perf_counter()
 
-    def _chunk_call(self, slot, toks, poss, kvl):
-        """Base chunk step plus the slot's block-table row operand."""
-        row = jnp.asarray(self.block_table[slot.index:slot.index + 1])
-        return self._chunk_step(self.params, self.cache, toks, poss,
-                                slot.index, kvl, row)
+    def _chunk_table(self, slot) -> tuple:
+        return (jnp.asarray(self.block_table[slot.index:slot.index + 1]),)
 
     def _register_prefill(self, slot, prompt) -> None:
         """Publish the fully-written prompt blocks to the prefix cache
@@ -825,49 +850,57 @@ class PagedBatchServer(_ServerBase):
         kv_raw: List[int] = []
         live_hist: List[int] = []
 
+        # Host spans on the profiler's clock (docs/serving.md,
+        # "Observability"): the loop is synchronous, so the host waits
+        # on the device only inside a ``*.pull`` span, and every device
+        # idle gap falls inside a named span.
         while self.sched.busy:
-            self._admit(decode_steps)
+            with TraceAnnotation("serve.iteration", step=decode_steps):
+                with TraceAnnotation("serve.admit") as span:
+                    waiting = len(self.sched.waiting)
+                    self._admit(decode_steps)
+                    span.set_metadata(
+                        admitted=waiting - len(self.sched.waiting),
+                        waiting=len(self.sched.waiting))
 
-            spent = 0
-            for slot in sorted(self.sched.prefilling_slots(),
-                               key=lambda s: s.rid):
-                while slot.prefilling and spent < self.prefill_budget:
-                    self._run_chunk(slot, decode_steps)
-                    prefill_chunks += 1
-                    spent += self.chunk
-                if spent >= self.prefill_budget:
-                    break
+                spent = 0
+                for slot in sorted(self.sched.prefilling_slots(),
+                                   key=lambda s: s.rid):
+                    while slot.prefilling and spent < self.prefill_budget:
+                        self._run_chunk(slot, decode_steps)
+                        prefill_chunks += 1
+                        spent += self.chunk
+                    if spent >= self.prefill_budget:
+                        break
 
-            active = self._grow_for_decode(self.sched.active_slots())
-            if not active:
-                continue
+                with TraceAnnotation("serve.grow") as span:
+                    preempted = self.preemptions
+                    active = self._grow_for_decode(self.sched.active_slots())
+                    span.set_metadata(preempted=self.preemptions - preempted)
+                if not active:
+                    continue
 
-            tok = np.array(self._cur)
-            pos = np.zeros((self.n_slots,), np.int32)
-            kvl = np.zeros((self.n_slots,), np.int32)
-            for s in active:
-                pos[s.index] = s.position
-                kvl[s.index] = s.position + 1
-            ntok, _, self.cache = self.decode(
-                self.params, self.cache, tok, pos, kvl,
-                jnp.asarray(self.block_table))
-            decode_steps += 1
-            occupancy.append(len(active))
-            live_hist.append(self.manager.live_blocks)
-            blocks = np.maximum(-(-kvl // self._kv_block), 1)
-            kv_fill.append(int(blocks.sum()) * self._kv_block)
-            kv_raw.append(int(kvl.sum()))
-            ntok_h = np.asarray(ntok)
-
-            for s in active:
-                req = self.requests[s.rid]
-                t = int(ntok_h[s.index])
-                req.tokens.append(t)
-                s.advance()
-                self._cur[s.index] = t
-                if s.generated >= s.max_new or t == self.eos_id:
-                    self._finish(req, decode_steps)
-                    self._free_slot(s)
+                with TraceAnnotation("serve.decode.prepare",
+                                     active=len(active)):
+                    tok = np.array(self._cur)
+                    pos = np.zeros((self.n_slots,), np.int32)
+                    kvl = np.zeros((self.n_slots,), np.int32)
+                    for s in active:
+                        pos[s.index] = s.position
+                        kvl[s.index] = s.position + 1
+                    table = jnp.asarray(self.block_table)
+                with TraceAnnotation("serve.decode.launch"):
+                    ntok, _, self.cache = self.decode(
+                        self.params, self.cache, tok, pos, kvl, table)
+                decode_steps += 1
+                occupancy.append(len(active))
+                live_hist.append(self.manager.live_blocks)
+                blocks = np.maximum(-(-kvl // self._kv_block), 1)
+                kv_fill.append(int(blocks.sum()) * self._kv_block)
+                kv_raw.append(int(kvl.sum()))
+                with TraceAnnotation("serve.decode.pull"):
+                    ntok_h = np.asarray(ntok)
+                self._emit(active, ntok_h, decode_steps)
 
         served = self._served
         wall = time.perf_counter() - t0
@@ -889,15 +922,11 @@ class PagedBatchServer(_ServerBase):
             st["prefix_hit_blocks"] / self._prompt_blocks_seen
             if self._prompt_blocks_seen else 0.0)
         if live_hist:
-            self.metrics["pool_live_blocks_mean"] = float(
-                np.mean(live_hist))
             self.metrics["pool_live_blocks_peak"] = int(np.max(live_hist))
             self.metrics["pool_utilization"] = (
                 float(np.mean(live_hist)) / self.pool_blocks)
             self.metrics["kv_live_bytes_peak"] = (
                 int(np.max(live_hist)) * self._block_bytes)
-            self.metrics["kv_live_bytes_mean"] = (
-                float(np.mean(live_hist)) * self._block_bytes)
         if kv_fill:
             denom = self.n_slots * self.capacity
             self.metrics["kv_read_frac"] = float(np.mean(kv_fill) / denom)
