@@ -48,7 +48,9 @@ Both kernels speak the **paged pool** layout (docs/paged_kv.md): with a
 resolves through the slot's table row inside the index maps — the DMA
 stream touches exactly the slot's blocks.  The contiguous (B, S, Hkv, D)
 layout is the same kernel over a pool of B·S/bk blocks addressed by an
-iota table, so there is one addressing path.  ``kv_block_size`` (the
+iota table, so there is one addressing path.  A ``layer`` scalar-
+prefetch operand reads one layer of a stacked (L, NB, BS, Hkv, D) pool
+in place; a pool without it is the L = 1 case.  ``kv_block_size`` (the
 tile helper shared with serve/kvcache.py) guarantees pool block ==
 kernel block, and ``check_kv_block`` states which blocks the chip's
 compiler accepts.
@@ -102,10 +104,10 @@ def check_kv_block(block: int, capacity: int) -> None:
                          f"divide the slot capacity {capacity}")
 
 
-def _kernel(kl_ref, tbl_ref, qp_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
-            scale: float, bk: int, n_k: int, hkv: int, window: int,
+def _kernel(kl_ref, tbl_ref, ly_ref, qp_ref, q_ref, k_ref, v_ref, pos_ref,
+            *rest, scale: float, bk: int, n_k: int, hkv: int, window: int,
             int8: bool):
-    del tbl_ref                          # consumed by the index maps only
+    del tbl_ref, ly_ref                  # consumed by the index maps only
     if int8:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -134,11 +136,14 @@ def _kernel(kl_ref, tbl_ref, qp_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
         if window > 0:
             valid &= pos > qp - window
         keep = valid.astype(jnp.float32)
+        if int8:
+            # scale tiles arrive (Hkv, bk): one (bk, Hkv) transpose each
+            ks, vs = ks_ref[0].T, vs_ref[0].T
         for h in range(hkv):
             q = q_ref[0, h].astype(jnp.float32) * scale      # (R, D)
             k = k_ref[0, :, h, :].astype(jnp.float32)        # (bk, D)
             if int8:
-                k = k * ks_ref[0, :, h:h + 1]                # (bk, 1) scales
+                k = k * ks[:, h:h + 1]                       # (bk, 1) scales
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(valid, s, NEG_INF)
@@ -154,7 +159,7 @@ def _kernel(kl_ref, tbl_ref, qp_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
             m_ref[h] = m_new
             v = v_ref[0, :, h, :].astype(jnp.float32)
             if int8:
-                v = v * vs_ref[0, :, h:h + 1]
+                v = v * vs[:, h:h + 1]
             pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             acc_ref[h] = acc_ref[h] * alpha + pv
@@ -196,9 +201,11 @@ def _q_tile(r: int, hkv: int, d: int) -> int:
 
 
 def _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
-            block_table, window, block_k, interpret):
+            block_table, window, block_k, interpret, layer=None):
     """The one pallas_call behind both entry points.  q: (B, Hkv, R, D)
-    grouped query rows; q_pos: (B, R) per-row positions."""
+    grouped query rows; q_pos: (B, R) per-row positions.  With ``layer``
+    the pool leaves are stacked (L, NB, BS, ...) and the layer is one
+    more scalar-prefetch operand of the index maps."""
     b, hkv, r, d = q.shape
     if block_table is None:
         # contiguous slot rows == a pool of B·n_k blocks whose table is
@@ -214,8 +221,13 @@ def _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
         block_table = jnp.arange(b * n_k, dtype=jnp.int32).reshape(b, n_k)
     else:
         # pool block == kernel KV block by construction (kv_block_size)
-        bk = k.shape[1]
+        bk = k.shape[-3]
         n_k = block_table.shape[1]
+    if layer is None:
+        # one layer's pool is the stacked form with L = 1 (a reshape)
+        k, v, k_scale, v_scale = (None if x is None else x[None]
+                                  for x in (k, v, k_scale, v_scale))
+        layer = 0
     int8 = k_scale is not None
     tq = _q_tile(r, hkv, d)
 
@@ -225,30 +237,40 @@ def _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
         last_live = jnp.maximum(pl.cdiv(kl[bi], bk) - 1, 0)
         return tbl[bi, jnp.minimum(ki, last_live)]
 
-    def row_index(bi, qi, ki, kl, tbl):
+    def row_index(bi, qi, ki, kl, tbl, ly):
         return (bi, 0, qi, 0)
 
-    def kv_index(bi, qi, ki, kl, tbl):
-        return (blk(bi, ki, kl, tbl), 0, 0, 0)
+    def kv_index(bi, qi, ki, kl, tbl, ly):
+        return (ly[0], blk(bi, ki, kl, tbl), 0, 0, 0)
 
-    def vec_index(bi, qi, ki, kl, tbl):
+    def scale_index(bi, qi, ki, kl, tbl, ly):
+        return (ly[0], blk(bi, ki, kl, tbl), 0, 0)
+
+    def pos_index(bi, qi, ki, kl, tbl, ly):
+        # positions are one (NB, BS) pool shared by every layer
         return (blk(bi, ki, kl, tbl), 0, 0)
 
+    # the layer axis is squeezed: the kernel sees (1, bk, Hkv, D) tiles
     in_specs = [
-        pl.BlockSpec((1, tq, 1), lambda bi, qi, ki, kl, tbl: (bi, qi, 0)),
+        pl.BlockSpec((1, tq, 1), lambda bi, qi, ki, *_: (bi, qi, 0)),
         pl.BlockSpec((1, hkv, tq, d), row_index),
-        pl.BlockSpec((1, bk, hkv, d), kv_index),
-        pl.BlockSpec((1, bk, hkv, d), kv_index),
-        pl.BlockSpec((1, 1, bk), vec_index),
+        pl.BlockSpec((pl.squeezed, 1, bk, hkv, d), kv_index),
+        pl.BlockSpec((pl.squeezed, 1, bk, hkv, d), kv_index),
+        pl.BlockSpec((1, 1, bk), pos_index),
     ]
     operands = [q_pos.astype(jnp.int32)[:, :, None], q, k, v,
                 cache_pos.reshape(-1, 1, bk)]
     if int8:
-        in_specs += [pl.BlockSpec((1, bk, hkv), vec_index)] * 2
-        operands += [k_scale, v_scale]
+        # Scales ride as (…, Hkv, bk), bk on the lanes: XLA's default
+        # TPU layout of an (…, BS, Hkv) f32 pool is that transpose, so
+        # the swap is a bitcast where a (bk, Hkv) block would copy the
+        # whole stacked pool into row-major order.
+        in_specs += [pl.BlockSpec((pl.squeezed, 1, hkv, bk),
+                                  scale_index)] * 2
+        operands += [jnp.swapaxes(x, -1, -2) for x in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, r // tq, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, tq, d), row_index),
@@ -265,7 +287,8 @@ def _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32), *operands)
+    )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
 
 @functools.partial(jax.jit,
@@ -275,6 +298,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  *, k_scale: Optional[jax.Array] = None,
                  v_scale: Optional[jax.Array] = None,
                  block_table: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None,
                  window: int = 0, block_k: int = BLOCK_K,
                  interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, G, D) grouped queries.
@@ -297,6 +321,11 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     and predicates their compute off, exactly as in the contiguous
     layout.  ``kv_len`` remains the *logical* per-slot fill.
 
+    ``layer`` (a scalar) reads one layer of a stacked pool: k/v (L, NB,
+    BS, Hkv, D), scales (L, NB, BS, Hkv), cache_pos (NB, BS) as before.
+    The index maps pick the layer, so the layer scan can carry the whole
+    pool and no per-layer slice is ever materialized (docs/paged_kv.md).
+
     Returns (B, Hkv, G, D) in q.dtype.
 
     Callers should size S to a multiple of the KV block (the servers
@@ -308,7 +337,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     b, _, g, _ = q.shape
     q_rows = jnp.broadcast_to(q_pos.astype(jnp.int32)[:, None], (b, g))
     return _attend(q, q_rows, k, v, cache_pos, kv_len, k_scale, v_scale,
-                   block_table, window, block_k, interpret)
+                   block_table, window, block_k, interpret, layer)
 
 
 @functools.partial(jax.jit,
@@ -319,6 +348,7 @@ def flash_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                         *, k_scale: Optional[jax.Array] = None,
                         v_scale: Optional[jax.Array] = None,
                         block_table: Optional[jax.Array] = None,
+                        layer: Optional[jax.Array] = None,
                         window: int = 0, block_k: int = BLOCK_K,
                         interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, R, D) grouped chunk queries — R = C·G rows ordered
@@ -333,11 +363,12 @@ def flash_chunk_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     ``block_table`` (B, n_blocks) int32 switches to the paged-pool
     layout exactly as in ``flash_decode``: k/v (NB, BS, Hkv, D), scales
     (NB, BS, Hkv), cache_pos (NB, BS), and the KV-block grid index
-    resolves through the slot's table row inside the index maps.
+    resolves through the slot's table row inside the index maps;
+    ``layer`` reads one layer of a stacked pool as in ``flash_decode``.
 
     The chunk's own KV must already be resident in the cache (written at
     its rows, or concatenated for ring layouts): in-chunk causality is
     decided purely by ``pos <= q_pos``, identical to the decode kernel.
     """
     return _attend(q, q_pos, k, v, cache_pos, kv_len, k_scale, v_scale,
-                   block_table, window, block_k, interpret)
+                   block_table, window, block_k, interpret, layer)

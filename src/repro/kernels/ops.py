@@ -100,10 +100,24 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+def _kv_operands(k_cache, v_cache, layer, path):
+    """(k, v, k_scale, v_scale) of float or ``Int8KV`` caches; the ref
+    oracle takes ``layer``'s slice of a stacked pool, the kernels read
+    it in place."""
+    if isinstance(k_cache, Int8KV):
+        out = (k_cache.q, v_cache.q, k_cache.scale, v_cache.scale)
+    else:
+        out = (k_cache, v_cache, None, None)
+    if layer is not None and path == "ref":
+        out = tuple(None if x is None else x[layer] for x in out)
+    return out
+
+
 def decode_attention(q, k_cache, v_cache, q_position, cache_positions, *,
                      window: int = 0,
                      kv_len: Optional[jax.Array] = None,
                      block_table: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None,
                      force: Optional[str] = None) -> jax.Array:
     """One-token decode attention against a slot-addressed KV cache.
 
@@ -124,18 +138,16 @@ def decode_attention(q, k_cache, v_cache, q_position, cache_positions, *,
     ``block_table[b, j]`` — inside the Pallas index maps on the kernel
     paths, by an explicit gather through the same table in the ref
     oracle.  ``kv_len`` is then mandatory (it is what fences a slot off
-    from the stale blocks its table tail names).
+    from the stale blocks its table tail names).  ``layer`` (a scalar)
+    marks the pools as stacked (L, NB, BS, ...) and reads that layer:
+    in the kernels' index maps, by a slice in the ref oracle.
 
     Int8 caches are dequantized per tile — inside the Pallas VMEM tile
     on the kernel paths, per ``lax.scan`` block in the ref simulation —
     so decode never materializes a float copy of the cache.
     """
     path = resolve_path(force)
-    if isinstance(k_cache, Int8KV):
-        k, k_scale = k_cache.q, k_cache.scale
-        v, v_scale = v_cache.q, v_cache.scale
-    else:
-        k, v, k_scale, v_scale = k_cache, v_cache, None, None
+    k, v, k_scale, v_scale = _kv_operands(k_cache, v_cache, layer, path)
     if block_table is not None and kv_len is None:
         raise ValueError("paged decode_attention requires kv_len")
     if path == "ref":
@@ -147,14 +159,14 @@ def decode_attention(q, k_cache, v_cache, q_position, cache_positions, *,
             q, k, v, q_position, cache_positions, window=window,
             kv_len=kv_len, k_scale=k_scale, v_scale=v_scale)
     b, _, hq, d = q.shape
-    hkv = k.shape[2]
+    hkv = k.shape[-2]
     if kv_len is None:
         kv_len = jnp.full((b,), k.shape[1], jnp.int32)
     out = fd.flash_decode(
         q.reshape(b, hkv, hq // hkv, d), k, v,
         q_position.astype(jnp.int32), cache_positions, kv_len,
         k_scale=k_scale, v_scale=v_scale, block_table=block_table,
-        window=window, interpret=(path == "interpret"))
+        layer=layer, window=window, interpret=(path == "interpret"))
     return out.reshape(b, 1, hq, d)
 
 
@@ -162,6 +174,7 @@ def chunk_attention(q, k_cache, v_cache, q_positions, cache_positions, *,
                     window: int = 0,
                     kv_len: Optional[jax.Array] = None,
                     block_table: Optional[jax.Array] = None,
+                    layer: Optional[jax.Array] = None,
                     force: Optional[str] = None) -> jax.Array:
     """Chunk-prefill attention: C query tokens per slot against the
     slot-addressed KV cache (the admission path of chunked pad-free
@@ -179,14 +192,10 @@ def chunk_attention(q, k_cache, v_cache, q_positions, cache_positions, *,
 
     ``block_table`` (B, n_blocks) selects the paged-pool layout exactly
     as in ``decode_attention`` (pool caches, table-resolved index maps /
-    ref gather, mandatory ``kv_len``).
+    ref gather, mandatory ``kv_len``, ``layer`` of a stacked pool).
     """
     path = resolve_path(force)
-    if isinstance(k_cache, Int8KV):
-        k, k_scale = k_cache.q, k_cache.scale
-        v, v_scale = v_cache.q, v_cache.scale
-    else:
-        k, v, k_scale, v_scale = k_cache, v_cache, None, None
+    k, v, k_scale, v_scale = _kv_operands(k_cache, v_cache, layer, path)
     if block_table is not None and kv_len is None:
         raise ValueError("paged chunk_attention requires kv_len")
     if path == "ref":
@@ -198,7 +207,7 @@ def chunk_attention(q, k_cache, v_cache, q_positions, cache_positions, *,
             q, k, v, q_positions, cache_positions, window=window,
             kv_len=kv_len, k_scale=k_scale, v_scale=v_scale)
     b, c, hq, d = q.shape
-    hkv = k.shape[2]
+    hkv = k.shape[-2]
     g = hq // hkv
     if kv_len is None:
         kv_len = jnp.full((b,), k.shape[1], jnp.int32)
@@ -210,7 +219,7 @@ def chunk_attention(q, k_cache, v_cache, q_positions, cache_positions, *,
     out = fd.flash_chunk_prefill(
         qg, k, v, qp_rows.astype(jnp.int32), cache_positions, kv_len,
         k_scale=k_scale, v_scale=v_scale, block_table=block_table,
-        window=window, interpret=(path == "interpret"))
+        layer=layer, window=window, interpret=(path == "interpret"))
     return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, c, hq, d)
 

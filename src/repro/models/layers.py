@@ -41,14 +41,17 @@ NEG_INF = -1e30
 # KV-cache representation helpers (PrecisionPolicy, serving tier)
 # ---------------------------------------------------------------------------
 def _constrain_decode_kv(cache):
+    """A stacked pool's leading layer axis stays unconstrained."""
+    def lead(x, n):
+        return (None,) * (x.ndim - n)
     if isinstance(cache, Int8KV):
         return Int8KV(
-            constrain(cache.q, ("act_batch", "act_cache_seq",
-                                "act_kv_heads", None)),
-            constrain(cache.scale, ("act_batch", "act_cache_seq",
-                                    "act_kv_heads")))
-    return constrain(cache, ("act_batch", "act_cache_seq",
-                             "act_kv_heads", None))
+            constrain(cache.q, lead(cache.q, 4) + (
+                "act_batch", "act_cache_seq", "act_kv_heads", None)),
+            constrain(cache.scale, lead(cache.scale, 3) + (
+                "act_batch", "act_cache_seq", "act_kv_heads")))
+    return constrain(cache, lead(cache, 4) + (
+        "act_batch", "act_cache_seq", "act_kv_heads", None))
 
 
 # ---------------------------------------------------------------------------
@@ -333,7 +336,8 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
                            policy: Optional[PrecisionPolicy] = None,
                            kv_len: Optional[jax.Array] = None,
                            active: Optional[jax.Array] = None,
-                           block_table: Optional[jax.Array] = None):
+                           block_table: Optional[jax.Array] = None,
+                           layer: Optional[jax.Array] = None):
     """One decode step.  x: (B, 1, d); position: (B,) absolute position;
     write_idx: (B,) slot to write KV into (ring index for sliding caches).
 
@@ -364,7 +368,10 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
     rows are routed out of bounds and dropped.  The scheduler owns the
     invariant that a written block has refcount 1 (prefix-shared blocks
     are never write targets), so the scatter targets are unique.  Only
-    full (non-ring) self-attention caches are ever paged.
+    full (non-ring) self-attention caches are ever paged.  ``layer`` (a
+    scalar) marks the pools as the stacked (L, NB, BS, ...) leaves the
+    layer scan carries: the row is written at ``[layer, blk, off]`` in
+    place and the kernel reads that layer through its index maps.
 
     Returns (out, new_cache_k, new_cache_v, new_cache_positions).
     """
@@ -400,12 +407,16 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
         off = write_idx % bs
         if active is not None:
             blk = jnp.where(active, blk, nb)
+        kv_at = (blk, off) if layer is None else (layer, blk, off)
 
         def upd(cache, new):
             # new: (B, 1, ...) — one row per slot, unique (blk, off)
             # targets by the refcount-1 write invariant
-            return cache.at[blk, off].set(new[:, 0].astype(cache.dtype),
-                                          mode="drop")
+            return cache.at[kv_at].set(new[:, 0].astype(cache.dtype),
+                                       mode="drop")
+        # one (NB, BS) position pool serves every layer
+        cache_positions = cache_positions.at[blk, off].set(position,
+                                                           mode="drop")
     else:
         def upd(cache, new):
             if active is None:
@@ -419,6 +430,7 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
                 return lax.dynamic_update_slice_in_dim(
                     c, jnp.where(a, n, old), i, axis=0)
             return jax.vmap(one)(cache, new, write_idx, active)
+        cache_positions = upd(cache_positions, position[:, None])
 
     if isinstance(cache_k, Int8KV):
         qk, qv = quant_kv(k), quant_kv(v)
@@ -431,7 +443,6 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
             v = dequant_kv(quant_kv(v), v.dtype)
         cache_k = upd(cache_k, k)
         cache_v = upd(cache_v, v)
-    cache_positions = upd(cache_positions, position[:, None])
     cache_k = _constrain_decode_kv(cache_k)
     cache_v = _constrain_decode_kv(cache_v)
     s_kv = cache_positions.shape[1]
@@ -446,7 +457,7 @@ def attention_decode_layer(p: dict, x: jax.Array, position: jax.Array,
         bound = kv_len
     o = decode_attention(q, cache_k, cache_v, position,
                          cache_positions, window=window, kv_len=bound,
-                         block_table=block_table)
+                         block_table=block_table, layer=layer)
     out = quant_matmul(o.reshape(b, 1, n_heads * head_dim), p["wo"],
                        policy=policy)
     return out, cache_k, cache_v, cache_positions
@@ -486,7 +497,8 @@ def attention_chunk_layer(p: dict, x: jax.Array, positions: jax.Array,
                           cross: bool = False,
                           policy: Optional[PrecisionPolicy] = None,
                           kv_len: Optional[jax.Array] = None,
-                          block_table: Optional[jax.Array] = None):
+                          block_table: Optional[jax.Array] = None,
+                          layer: Optional[jax.Array] = None):
     """One chunk-prefill step: C tokens written unpadded into the slot's
     cache rows, attending over the slot's live KV prefix plus themselves.
 
@@ -515,6 +527,7 @@ def attention_chunk_layer(p: dict, x: jax.Array, positions: jax.Array,
     pad-tail rows included, stamped position −1, so a recycled block can
     never leak a stale position inside the post-write fill — and the
     attention resolves through the same table in the kernel index maps.
+    ``layer`` marks stacked pools exactly as in ``attention_decode_layer``.
 
     Returns (out (B, C, d), new_cache_k, new_cache_v, new_cache_positions).
     """
@@ -591,18 +604,21 @@ def attention_chunk_layer(p: dict, x: jax.Array, positions: jax.Array,
             tgt = write_idx[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
             blk = jnp.take_along_axis(block_table, tgt // bs, axis=1)
             off = tgt % bs
+            kv_at = (blk, off) if layer is None else (layer, blk, off)
 
             def upd(cache, new):
                 # (B, C) index pairs — unique targets per refcount-1
                 # write invariant (shared prefix blocks are skipped by
                 # the scheduler, never written)
-                return cache.at[blk, off].set(new.astype(cache.dtype))
+                return cache.at[kv_at].set(new.astype(cache.dtype))
+            cache_positions = cache_positions.at[blk, off].set(positions)
         else:
             def upd(cache, new):
                 return jax.vmap(
                     lambda cc, n, i: lax.dynamic_update_slice_in_dim(
                         cc, n.astype(cc.dtype), i, axis=0)
                 )(cache, new, write_idx)
+            cache_positions = upd(cache_positions, positions)
 
         if isinstance(cache_k, Int8KV):
             qk, qv = quant_kv(k), quant_kv(v)
@@ -613,14 +629,13 @@ def attention_chunk_layer(p: dict, x: jax.Array, positions: jax.Array,
         else:
             cache_k = upd(cache_k, k)
             cache_v = upd(cache_v, v)
-        cache_positions = upd(cache_positions, positions)
         s_kv = cache_positions.shape[1]
         bound = None if kv_len is None else jnp.clip(kv_len, 0, s_kv)
         if block_table is not None:
             bound = kv_len
         o = chunk_attention(q, cache_k, cache_v, positions,
                             cache_positions, kv_len=bound,
-                            block_table=block_table)
+                            block_table=block_table, layer=layer)
     cache_k = _constrain_decode_kv(cache_k)
     cache_v = _constrain_decode_kv(cache_v)
     out = quant_matmul(o.reshape(b, c, n_heads * head_dim), p["wo"],

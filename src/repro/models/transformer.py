@@ -153,12 +153,13 @@ def mamba_block(cfg: ArchConfig, p, x, state=None):
 
 def dense_block_decode(cfg: ArchConfig, p, x, position, cache_k, cache_v,
                        cache_pos, write_idx, *, window=0, policy=None,
-                       kv_len=None, active=None, block_table=None):
+                       kv_len=None, active=None, block_table=None,
+                       layer=None):
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
     attn_out, ck, cv, cp = attention_decode_layer(
         p["attn"], h, position, cache_k, cache_v, cache_pos, write_idx,
         policy=policy, kv_len=kv_len, active=active,
-        block_table=block_table, **_attn_kwargs(cfg, window))
+        block_table=block_table, layer=layer, **_attn_kwargs(cfg, window))
     x = x + attn_out
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     x = x + swiglu_mlp(p["mlp"], h, policy)
@@ -167,12 +168,12 @@ def dense_block_decode(cfg: ArchConfig, p, x, position, cache_k, cache_v,
 
 def moe_block_decode(cfg: ArchConfig, p, x, position, cache_k, cache_v,
                      cache_pos, write_idx, policy=None, kv_len=None,
-                     active=None, block_table=None):
+                     active=None, block_table=None, layer=None):
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
     attn_out, ck, cv, cp = attention_decode_layer(
         p["attn"], h, position, cache_k, cache_v, cache_pos, write_idx,
         policy=policy, kv_len=kv_len, active=active,
-        block_table=block_table, **_attn_kwargs(cfg))
+        block_table=block_table, layer=layer, **_attn_kwargs(cfg))
     x = x + attn_out
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     x = x + moe_layer(p["moe"], h, cfg)
@@ -200,11 +201,11 @@ def mamba_block_decode(cfg: ArchConfig, p, x, state, active=None):
 # ---------------------------------------------------------------------------
 def dense_block_chunk(cfg: ArchConfig, p, x, positions, cache_k, cache_v,
                       cache_pos, write_idx, *, window=0, policy=None,
-                      kv_len=None, block_table=None):
+                      kv_len=None, block_table=None, layer=None):
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
     attn_out, ck, cv, cp = attention_chunk_layer(
         p["attn"], h, positions, cache_k, cache_v, cache_pos, write_idx,
-        policy=policy, kv_len=kv_len, block_table=block_table,
+        policy=policy, kv_len=kv_len, block_table=block_table, layer=layer,
         **_attn_kwargs(cfg, window))
     x = x + attn_out
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
@@ -214,11 +215,11 @@ def dense_block_chunk(cfg: ArchConfig, p, x, positions, cache_k, cache_v,
 
 def moe_block_chunk(cfg: ArchConfig, p, x, positions, cache_k, cache_v,
                     cache_pos, write_idx, policy=None, kv_len=None,
-                    block_table=None):
+                    block_table=None, layer=None):
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
     attn_out, ck, cv, cp = attention_chunk_layer(
         p["attn"], h, positions, cache_k, cache_v, cache_pos, write_idx,
-        policy=policy, kv_len=kv_len, block_table=block_table,
+        policy=policy, kv_len=kv_len, block_table=block_table, layer=layer,
         **_attn_kwargs(cfg))
     x = x + attn_out
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
@@ -238,6 +239,40 @@ def mamba_block_chunk(cfg: ArchConfig, p, x, state, mask, fill):
 # ---------------------------------------------------------------------------
 # Trunk (pattern-dispatched scans)
 # ---------------------------------------------------------------------------
+def _pool_scan(step, x, xs, pools, paged: bool):
+    """Scan ``step(h, xs_l, pools_l, layer) -> (h, ys_l, pools_l)`` over
+    the leading (layer or group) axis of ``xs`` and of the attention
+    caches ``pools``; returns ``(x, ys, pools)``.
+
+    Slot-addressed caches ride as ``xs``/``ys``: the step gets one
+    layer's slice and ``layer`` is None.  Paged pools ride whole in the
+    carry: the step writes its rows at ``[layer, blk, off]`` and the
+    kernel reads the layer through its index maps, so the donated pool
+    is updated in place.  As ``xs``/``ys`` they would cost a copy of
+    each whole pool before the loop (``ys`` may not overwrite the ``xs``
+    the loop still reads), a per-layer slice for the Pallas call (which
+    cannot read through a dynamic-slice) and a write of that slice back,
+    where a step writes a few rows (docs/paged_kv.md).
+    """
+    if not paged:
+        def body(h, a):
+            x_l, pools_l = a
+            h, y, pools_l = step(h, x_l, pools_l, None)
+            return h, (y, pools_l)
+        x, (ys, pools) = lax.scan(body, x, (xs, pools))
+        return x, ys, pools
+
+    def carried(carry, a):
+        h, pools_c = carry
+        x_l, layer = a
+        h, y, pools_c = step(h, x_l, pools_c, layer)
+        return (h, pools_c), y
+    n = jax.tree.leaves(pools)[0].shape[0]
+    (x, pools), ys = lax.scan(carried, (x, pools),
+                              (xs, jnp.arange(n, dtype=jnp.int32)))
+    return x, ys, pools
+
+
 def trunk_forward(cfg: ArchConfig, params, x, positions, *,
                   remat: str = "none", collect_cache: bool = False,
                   policy: Optional[PrecisionPolicy] = None):
@@ -344,31 +379,31 @@ def trunk_decode(cfg: ArchConfig, params, x, position, cache, *,
 
     ``block_table`` (B, n_blocks) marks the cache as **paged**: the
     full-attention KV leaves are block pools addressed through the table
-    (positions in ``cache["pool_pos"]``), while sliding-window ring
-    caches and SSM state stay slot-addressed — they are O(window) /
+    (positions in ``cache["pool_pos"]``) and carried whole through the
+    layer scan, updated in place (``_pool_scan``), while sliding-window
+    ring caches and SSM state stay slot-addressed — they are O(window) /
     O(state) per slot already, there is no capacity tail to reclaim
     (docs/paged_kv.md).
     """
     pat = layer_pattern(cfg)
     new_cache = dict(cache)
+    paged = block_table is not None
     # paged caches keep full-attention positions in the (NB, BS) pool
-    full_pos = cache["pool_pos" if block_table is not None else "full_pos"] \
+    full_pos = cache["pool_pos" if paged else "full_pos"] \
         if pat["kind"] != "uniform_ssm" else None
 
     if pat["kind"] in ("uniform_dense", "uniform_moe"):
         is_moe = pat["kind"] == "uniform_moe"
 
-        def body(h, pc):
-            p, ck, cv = pc
+        def body(h, p, kv, layer):
             fn = moe_block_decode if is_moe else dense_block_decode
-            h, ck, cv, cp = fn(cfg, p, h, position, ck, cv,
-                               full_pos, write_full, policy=policy,
-                               kv_len=kv_len, active=active,
-                               block_table=block_table)
-            return h, (ck, cv)
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"],
-                                         cache["k"], cache["v"]))
-        new_cache["k"], new_cache["v"] = ks, vs
+            h, ck, cv, _ = fn(cfg, p, h, position, *kv,
+                              full_pos, write_full, policy=policy,
+                              kv_len=kv_len, active=active,
+                              block_table=block_table, layer=layer)
+            return h, None, (ck, cv)
+        x, _, (new_cache["k"], new_cache["v"]) = _pool_scan(
+            body, x, params["blocks"], (cache["k"], cache["v"]), paged)
 
     elif pat["kind"] == "uniform_ssm":
         def body(h, pc):
@@ -391,21 +426,19 @@ def trunk_decode(cfg: ArchConfig, params, x, position, cache, *,
                 active=active)
             return h, (ck, cv)
 
-        def group_body(h, pc):
-            p, lk, lv, gk, gv = pc
+        def group_body(h, xs_l, gkv, layer):
+            p, lk, lv = xs_l
             h, (lks, lvs) = lax.scan(local_body, h, (p["local"], lk, lv))
             h, gk, gv, _ = dense_block_decode(
-                cfg, p["global"], h, position, gk, gv,
+                cfg, p["global"], h, position, *gkv,
                 full_pos, write_full, policy=policy, kv_len=kv_len,
-                active=active, block_table=block_table)
-            return h, (lks, lvs, gk, gv)
+                active=active, block_table=block_table, layer=layer)
+            return h, (lks, lvs), (gk, gv)
 
-        x, (lks, lvs, gks, gvs) = lax.scan(
+        x, (lks, lvs), (gks, gvs) = _pool_scan(
             group_body, x,
-            ({"local": params["groups"]["local"],
-              "global": params["groups"]["global"]},
-             cache["local_k"], cache["local_v"],
-             cache["global_k"], cache["global_v"]))
+            (params["groups"], cache["local_k"], cache["local_v"]),
+            (cache["global_k"], cache["global_v"]), paged)
         new_cache.update(local_k=lks, local_v=lvs,
                          global_k=gks, global_v=gvs)
         if "tail_k" in cache:
@@ -423,21 +456,19 @@ def trunk_decode(cfg: ArchConfig, params, x, position, cache, *,
                                        active=active)
             return h, tuple(st)
 
-        def group_body(h, pc):
-            p, st, ck, cv = pc
-            h, states = lax.scan(mamba_body, h, (p, tuple(st)))
+        def group_body(h, pst, kv, layer):
+            p, st = pst
+            h, states = lax.scan(mamba_body, h, (p, st))
             h, ck, cv, _ = dense_block_decode(
-                cfg, shared, h, position, ck, cv,
+                cfg, shared, h, position, *kv,
                 full_pos, write_full, policy=policy, kv_len=kv_len,
-                active=active, block_table=block_table)
-            return h, (states, ck, cv)
+                active=active, block_table=block_table, layer=layer)
+            return h, states, (ck, cv)
 
-        x, (states, ks, vs) = lax.scan(
-            group_body, x,
-            (params["groups"], tuple(cache["ssm"]),
-             cache["attn_k"], cache["attn_v"]))
+        x, states, (new_cache["attn_k"], new_cache["attn_v"]) = _pool_scan(
+            group_body, x, (params["groups"], tuple(cache["ssm"])),
+            (cache["attn_k"], cache["attn_v"]), paged)
         new_cache["ssm"] = ssm_mod.SSMState(*states)
-        new_cache["attn_k"], new_cache["attn_v"] = ks, vs
     else:
         raise ValueError(pat)
 
@@ -623,22 +654,22 @@ def trunk_prefill_chunk(cfg: ArchConfig, params, x, positions, cache, *,
     new_cache = dict(cache)
     mask = positions >= 0
     fill = mask.sum(axis=1).astype(jnp.int32)
-    full_pos = cache["pool_pos" if block_table is not None else "full_pos"] \
+    paged = block_table is not None
+    full_pos = cache["pool_pos" if paged else "full_pos"] \
         if pat["kind"] != "uniform_ssm" else None
 
     if pat["kind"] in ("uniform_dense", "uniform_moe"):
         is_moe = pat["kind"] == "uniform_moe"
 
-        def body(h, pc):
-            p, ck, cv = pc
+        def body(h, p, kv, layer):
             fn = moe_block_chunk if is_moe else dense_block_chunk
-            h, ck, cv, cp = fn(cfg, p, h, positions, ck, cv,
-                               full_pos, write_full, policy=policy,
-                               kv_len=kv_len, block_table=block_table)
-            return h, (ck, cv)
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"],
-                                         cache["k"], cache["v"]))
-        new_cache["k"], new_cache["v"] = ks, vs
+            h, ck, cv, _ = fn(cfg, p, h, positions, *kv,
+                              full_pos, write_full, policy=policy,
+                              kv_len=kv_len, block_table=block_table,
+                              layer=layer)
+            return h, None, (ck, cv)
+        x, _, (new_cache["k"], new_cache["v"]) = _pool_scan(
+            body, x, params["blocks"], (cache["k"], cache["v"]), paged)
 
     elif pat["kind"] == "uniform_ssm":
         def body(h, pc):
@@ -660,21 +691,19 @@ def trunk_prefill_chunk(cfg: ArchConfig, params, x, positions, cache, *,
                 write_full, window=w, policy=policy, kv_len=kv_len)
             return h, (ck, cv)
 
-        def group_body(h, pc):
-            p, lk, lv, gk, gv = pc
+        def group_body(h, xs_l, gkv, layer):
+            p, lk, lv = xs_l
             h, (lks, lvs) = lax.scan(local_body, h, (p["local"], lk, lv))
             h, gk, gv, _ = dense_block_chunk(
-                cfg, p["global"], h, positions, gk, gv,
+                cfg, p["global"], h, positions, *gkv,
                 full_pos, write_full, policy=policy, kv_len=kv_len,
-                block_table=block_table)
-            return h, (lks, lvs, gk, gv)
+                block_table=block_table, layer=layer)
+            return h, (lks, lvs), (gk, gv)
 
-        x, (lks, lvs, gks, gvs) = lax.scan(
+        x, (lks, lvs), (gks, gvs) = _pool_scan(
             group_body, x,
-            ({"local": params["groups"]["local"],
-              "global": params["groups"]["global"]},
-             cache["local_k"], cache["local_v"],
-             cache["global_k"], cache["global_v"]))
+            (params["groups"], cache["local_k"], cache["local_v"]),
+            (cache["global_k"], cache["global_v"]), paged)
         new_cache.update(local_k=lks, local_v=lvs,
                          global_k=gks, global_v=gvs)
         if "tail_k" in cache:
@@ -692,21 +721,19 @@ def trunk_prefill_chunk(cfg: ArchConfig, params, x, positions, cache, *,
                                       mask, fill)
             return h, tuple(st)
 
-        def group_body(h, pc):
-            p, st, ck, cv = pc
-            h, states = lax.scan(mamba_body, h, (p, tuple(st)))
+        def group_body(h, pst, kv, layer):
+            p, st = pst
+            h, states = lax.scan(mamba_body, h, (p, st))
             h, ck, cv, _ = dense_block_chunk(
-                cfg, shared, h, positions, ck, cv,
+                cfg, shared, h, positions, *kv,
                 full_pos, write_full, policy=policy, kv_len=kv_len,
-                block_table=block_table)
-            return h, (states, ck, cv)
+                block_table=block_table, layer=layer)
+            return h, states, (ck, cv)
 
-        x, (states, ks, vs) = lax.scan(
-            group_body, x,
-            (params["groups"], tuple(cache["ssm"]),
-             cache["attn_k"], cache["attn_v"]))
+        x, states, (new_cache["attn_k"], new_cache["attn_v"]) = _pool_scan(
+            group_body, x, (params["groups"], tuple(cache["ssm"])),
+            (cache["attn_k"], cache["attn_v"]), paged)
         new_cache["ssm"] = ssm_mod.SSMState(*states)
-        new_cache["attn_k"], new_cache["attn_v"] = ks, vs
     else:
         raise ValueError(pat)
 

@@ -1,7 +1,9 @@
 """Shared test configuration.
 
-Provides a deterministic fallback shim for ``hypothesis`` when the real
-package is not installed (this container ships without it).  Property
+Drops JAX's compiled programs after every test
+(``_release_compiled_programs``), and provides a deterministic fallback
+shim for ``hypothesis`` when the real package is not installed (this
+container ships without it).  Property
 tests then degrade to a fixed sweep of seeded examples instead of
 breaking collection for the whole file.  The shim covers exactly the
 subset the suite uses: ``@settings(max_examples=..., deadline=...)``,
@@ -12,6 +14,9 @@ import random
 import sys
 import types
 import zlib
+
+import jax
+import pytest
 
 
 def _install_hypothesis_stub() -> None:
@@ -69,3 +74,14 @@ try:
     import hypothesis  # noqa: F401
 except ModuleNotFoundError:
     _install_hypothesis_stub()
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test.  The CPU backend
+    maps memory for every program it compiles and JAX's caches keep
+    them alive, so a worker that runs a whole file of op-by-op serving
+    references reaches the kernel's per-process map limit
+    (``vm.max_map_count``, 65530 by default) and dies mid-file."""
+    yield
+    jax.clear_caches()

@@ -10,10 +10,12 @@ The contracts under test:
 * Kernel parity *through the block table*: interpret-mode Pallas
   ``flash_decode``/``flash_chunk_prefill`` against the ref oracle that
   gathers through the same table — scrambled physical placements,
-  ragged ``kv_len``, empty slots, Int8KV — so the paged addressing
-  itself is pinned, not just the softmax math.
+  ragged ``kv_len``, empty slots, Int8KV, and the first and last layer
+  of a stacked pool read in place — so the paged addressing itself is
+  pinned, not just the softmax math.
 * Paged continuous serving is token-exact vs the unpadded one-shot
-  reference on {uniform, ring, ssm, hybrid} × {float, int8}, including
+  reference on {uniform, ring, ssm, hybrid} × {float, int8} (the uniform
+  family also through the Pallas kernels in interpret mode), including
   forced preempt-and-recompute and physical prefix sharing (asserted by
   pool accounting: live blocks < Σ per-request blocks).
 * Slot/block recycling under churn — release → re-admit → preemption →
@@ -30,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import configs
+from repro import configs, flags
 from repro.kernels import flash_decode as fd
 from repro.kernels import ref
 from repro.models import api
@@ -168,9 +170,36 @@ def _paged_case(rng, b, n_tbl, nb, bs, hkv, d, fills, *, int8=False):
     return out
 
 
+_N_LAYERS = 3
+# None: one layer's pool; 0 and L − 1: that layer of a stacked pool
+_LAYERS = pytest.mark.parametrize("layer", [None, 0, _N_LAYERS - 1],
+                                  ids=["pool", "layer0", "layer_last"])
+
+
+def _pool_operands(rng, c, layer):
+    """Kernel operands (k, v, scales, layer) for ``c``'s pool: as is, or
+    stacked as layer ``layer`` of ``_N_LAYERS`` whose other layers hold
+    other values, so a read of the wrong layer cannot pass."""
+    names = ("k", "v", "ks", "vs")
+    if layer is None:
+        leaves = [c.get(n) for n in names]
+    else:
+        leaves = []
+        for n in names:
+            x = c.get(n)
+            if x is not None:
+                other = rng.randn(_N_LAYERS, *x.shape) * 3
+                x = jnp.asarray(other, x.dtype).at[layer].set(x)
+            leaves.append(x)
+        layer = jnp.int32(layer)
+    k, v, ks, vs = leaves
+    return k, v, dict(k_scale=ks, v_scale=vs, layer=layer)
+
+
+@_LAYERS
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("g", [1, 2])
-def test_paged_flash_decode_parity(int8, g):
+def test_paged_flash_decode_parity(int8, g, layer):
     rng = np.random.RandomState(0)
     b, hkv, d, bs, n_tbl, nb = 4, 2, 16, 8, 4, 9
     fills = np.array([5, 0, 32, 17], np.int32)   # ragged + empty + full
@@ -178,9 +207,9 @@ def test_paged_flash_decode_parity(int8, g):
     q = rng.randn(b, hkv, g, d).astype(np.float32)
     qp = jnp.asarray(np.maximum(fills - 1, 0), jnp.int32)
     scales = dict(k_scale=c.get("ks"), v_scale=c.get("vs"))
-    got = fd.flash_decode(jnp.asarray(q), c["k"], c["v"], qp, c["pos"],
-                          c["kvl"], block_table=c["table"],
-                          interpret=True, **scales)
+    k, v, kw = _pool_operands(rng, c, layer)
+    got = fd.flash_decode(jnp.asarray(q), k, v, qp, c["pos"], c["kvl"],
+                          block_table=c["table"], interpret=True, **kw)
     q_ref = q.reshape(b, hkv * g, d)[:, None]
     want = ref.paged_decode_attention_ref(
         jnp.asarray(q_ref), c["k"], c["v"], qp, c["pos"], c["table"],
@@ -190,8 +219,9 @@ def test_paged_flash_decode_parity(int8, g):
     assert np.abs(np.asarray(got)[1]).max() == 0.0   # empty slot → zeros
 
 
+@_LAYERS
 @pytest.mark.parametrize("int8", [False, True])
-def test_paged_chunk_prefill_parity(int8):
+def test_paged_chunk_prefill_parity(int8, layer):
     rng = np.random.RandomState(1)
     b, hkv, g, cq, d, bs, n_tbl, nb = 3, 2, 2, 4, 16, 8, 4, 8
     fills = np.array([8, 20, 12], np.int32)      # post-write fills p + C
@@ -204,9 +234,10 @@ def test_paged_chunk_prefill_parity(int8):
     q = rng.randn(b, hkv, cq * g, d).astype(np.float32)
     qp_rows = np.repeat(qpos, g, axis=1)         # (B, C·G), (query, group)
     scales = dict(k_scale=c.get("ks"), v_scale=c.get("vs"))
+    k, v, kw = _pool_operands(rng, c, layer)
     got = fd.flash_chunk_prefill(
-        jnp.asarray(q), c["k"], c["v"], jnp.asarray(qp_rows), c["pos"],
-        c["kvl"], block_table=c["table"], interpret=True, **scales)
+        jnp.asarray(q), k, v, jnp.asarray(qp_rows), c["pos"], c["kvl"],
+        block_table=c["table"], interpret=True, **kw)
     q_ref = q.reshape(b, hkv, cq, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, cq, hkv * g, d)
     want = ref.paged_chunk_attention_ref(
@@ -267,6 +298,32 @@ def test_paged_serving_token_exact_int8(arch):
     fq.run()
     assert [r.tokens for r in reqs] == [r.tokens for r in freqs], \
         f"{arch}: int8 diverged from fake-quant oracle"
+
+
+@pytest.mark.parametrize("precision", ["float", "int8"])
+def test_paged_serving_interpret_token_exact(precision, monkeypatch):
+    """Paged serving through the Pallas kernels (interpret mode): the
+    layer scan carries the stacked pool and each kernel call reads its
+    layer in place.  Float == the unpadded reference on the same kernel
+    path; int8 == the fake-quant oracle through the same schedule."""
+    monkeypatch.setitem(flags.FLAGS, "kernel_path", "interpret")
+    cfg, params = _setup("internlm2-1.8b")
+    prompts = _workload(cfg)[:3]
+    budgets = list(_BUDGETS[:3])
+    srv = PagedBatchServer(cfg, params, precision=precision, **_PAGED_KW)
+    reqs = srv.submit(prompts, max_new_tokens=budgets)
+    srv.run()
+    if precision == "float":
+        refs = [_reference_decode(cfg, params, p, b)
+                for p, b in zip(prompts, budgets)]
+    else:
+        fq = PagedBatchServer(cfg, params, precision="int8_fakequant",
+                              **_PAGED_KW)
+        fq.submit(prompts, max_new_tokens=budgets)
+        fq.run()
+        refs = [r.tokens for r in fq.requests.values()]
+    assert [r.tokens for r in reqs] == refs, \
+        f"{precision}: paged serving through the kernels diverged"
 
 
 @pytest.mark.parametrize("precision", ["float", "int8"])

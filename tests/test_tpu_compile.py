@@ -9,20 +9,27 @@ compiler the kernels at ``internlm2-1.8b`` widths (head_dim 128, 8 kv
 heads, G = 2, 128-row pool blocks, 256-token chunks), ``mamba_scan`` at
 ``zamba2-2.7b`` widths, and the full-width paged decode and chunk steps,
 and check that each compiles into a Pallas kernel (``tpu_custom_call``)
-that fits one chip.
+that fits one chip.  At the chip benchmark's pool the paged steps must
+also update the donated pool in place: no whole-pool copy and no
+per-layer pool slice in the compiled program.
 
 The topology is described only inside the module fixture, so importing
 this file touches no TPU library, and the compilation cache is off
 around these compiles (an entry written for a described chip cannot be
 read back without one).
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs, flags
-from repro.core.quantize import policy_for, quantize_model_params
+from repro.core.quantize import (QTensor, policy_for,
+                                 quantize_model_params)
 from repro.kernels import flash_decode as fd
 from repro.kernels import int8_matmul as im
 from repro.kernels import mamba_scan as ms
@@ -35,6 +42,8 @@ from repro.serve.serve_step import (make_paged_chunk_prefill_step,
 HKV, G, D = 8, 2, 128
 SLOTS, CAPACITY, BLOCK, CHUNK = 8, 1024, 128, 256
 V5E_HBM = 16 * 2**30
+# the chip benchmark's internlm2-chat serving shape: 192 pool blocks
+CELL_SLOTS, CELL_CAPACITY, CELL_BLOCKS = 16, 1536, 192
 
 
 @pytest.fixture(scope="module")
@@ -188,3 +197,76 @@ def test_paged_steps_fit_one_chip(one_chip, precision, monkeypatch):
         held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
         assert held < V5E_HBM, held
+
+
+def _results(hlo_text):
+    """(name, opcode, shape) of every instruction in an HLO module."""
+    pat = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\]"
+                     r"(?:\{[^}]*\})? ([\w-]+)\(", re.M)
+    for name, dims, opcode in pat.findall(hlo_text):
+        yield name, opcode, tuple(int(x) for x in dims.split(",") if x)
+
+
+@pytest.mark.parametrize("precision", ["float", "int8"])
+def test_paged_steps_update_pool_in_place(one_chip, precision, monkeypatch):
+    """The full-width paged decode and chunk steps at the benchmark
+    cell's pool, jitted with the cache donated as ``PagedBatchServer``
+    builds them, update the pool in place: the layer scan carries the
+    stacked pool and the kernels index its layer, so no whole-pool copy
+    or write-back and no per-layer pool slice appears, and the
+    temporaries hold no more than the bf16 casts of the float weights
+    and one pool leaf."""
+    monkeypatch.setitem(flags.FLAGS, "kernel_path", "pallas")
+    s = one_chip
+    cfg = configs.get("internlm2-1.8b")
+    prec = policy_for(precision)
+    n_table = CELL_CAPACITY // BLOCK
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(s, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda p: quantize_model_params(p, prec), abstract_params(cfg)))
+    cache = on_chip(abstract_paged_cache(cfg, CELL_SLOTS, CELL_CAPACITY,
+                                         CELL_BLOCKS, prec, BLOCK))
+    vec = _spec(s, (CELL_SLOTS,), jnp.int32)
+    decode = jax.jit(make_paged_decode_step(cfg, policy=prec),
+                     donate_argnums=(1,)).lower(
+        params, cache, vec, vec, vec,
+        _spec(s, (CELL_SLOTS, n_table), jnp.int32)).compile()
+    axes = paged_slot_axes(cfg, CELL_SLOTS, CELL_CAPACITY, CELL_BLOCKS,
+                           prec, BLOCK)
+    row = _spec(s, (1, CHUNK), jnp.int32)
+    chunk = jax.jit(make_paged_chunk_prefill_step(cfg, axes=axes,
+                                                  policy=prec),
+                    donate_argnums=(1,)).lower(
+        params, cache, row, row, _spec(s, (), jnp.int32),
+        _spec(s, (1,), jnp.int32),
+        _spec(s, (1, n_table), jnp.int32)).compile()
+
+    pools = jax.tree.leaves({k: cache[k] for k in ("k", "v")})
+    whole = {p.shape for p in pools}
+    per_layer = {p.shape[1:] for p in pools}
+    floats = jax.tree.leaves(params,
+                             is_leaf=lambda x: isinstance(x, QTensor))
+    casts = sum(math.prod(w.shape) * 2 for w in floats
+                if not isinstance(w, QTensor)
+                and w.dtype == jnp.float32 and w.ndim >= 2)
+    one_pool = max(math.prod(p.shape) * np.dtype(p.dtype).itemsize
+                   for p in pools)
+    for compiled in (decode, chunk):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        for name, opcode, shape in _results(text):
+            if shape in whole:
+                # copy-start/-done pairs that move the small f32 Int8KV
+                # scale pools to on-chip memory and back are the
+                # compiler's prefetch, not a copy in HBM
+                assert opcode != "copy", name
+                assert not (opcode == "fusion" and "copy" in name), name
+                assert opcode != "dynamic-update-slice", name
+                assert "dynamic-update-slice" not in name, name
+            squeezed = tuple(shape[1:]) if shape[:1] == (1,) else shape
+            assert squeezed not in per_layer, (name, shape)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < casts + one_pool, (temp, casts, one_pool)
