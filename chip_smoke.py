@@ -42,7 +42,7 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 from repro import configs, flags
-from repro.core.quantize import policy_for, quantize_model_params
+from repro.core.quantize import policy_for, serving_params
 from repro.kernels import ops
 from repro.launch import compile_cache
 from repro.models.params import init_params, param_count
@@ -135,7 +135,7 @@ def compare_paths(cfg, params, *, precision: str, capacity: int) -> dict:
     identical inputs.  Returns the max |logit difference| of each step
     and the reference's max |logit|."""
     prec = policy_for(precision)
-    params = quantize_model_params(params, prec)      # as a server holds
+    params = serving_params(params, prec, cfg.activation_dtype)  # as held
     block = kv_block_size(capacity)
     n_table = capacity // block
     pool = SLOTS * n_table
@@ -220,7 +220,8 @@ def run_phases(cfg, params, prompts) -> None:
         if precision == "int8":
             # the int8 phases hold int8 weights only: quantizing once here
             # frees the 7 GiB of float projections the servers never read
-            params = quantize_model_params(params, policy_for(precision))
+            params = serving_params(params, policy_for(precision),
+                                    cfg.activation_dtype)
             jax.block_until_ready(params)
         tag = "a" if precision == "float" else "d"
         tokens, m = serve(cfg, params, prompts, precision=precision,

@@ -13,6 +13,7 @@ backend, and the serving path pairs with ``kernels/int8_matmul`` on TPU.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
@@ -245,9 +246,19 @@ def maybe_quant_kv(policy: Optional[PrecisionPolicy], x: jax.Array):
 # ---------------------------------------------------------------------------
 # Param sub-trees whose 2D+ leaves feed ops.quant_matmul.  MoE expert
 # banks and SSM dynamics keep float (their einsum dispatch never routes
-# through the dense matmul entry point); embed/unembed stay float so
-# logits keep full precision.
+# through the dense matmul entry point); embed/unembed stay out of int8
+# so logits keep full precision.
 QUANT_SCOPES = ("attn", "mlp", "xattn")
+# Top-level tables that every use casts to the activation dtype first
+# (``transformer.embed_tokens`` / ``unembed``).
+TABLES = ("embed", "unembed")
+
+
+def _is_projection(path, leaf) -> bool:
+    """A >=2-D float leaf under QUANT_SCOPES: a weight that reaches the
+    model only through ``ops.quant_matmul``."""
+    return (any(getattr(k, "key", None) in QUANT_SCOPES for k in path)
+            and leaf.ndim >= 2 and jnp.issubdtype(leaf.dtype, jnp.floating))
 
 
 @jax.jit
@@ -263,26 +274,57 @@ def _leaf_qtensor(w: jax.Array) -> QTensor:
     return QTensor(q.astype(jnp.int8), scale.astype(jnp.float32))
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _leaf_cast(w: jax.Array, dtype) -> jax.Array:
+    return w.astype(dtype)
+
+
+def _map_params(fn, params):
+    """``fn(path, leaf)`` over every leaf; ``QTensor``s pass through
+    whole: quantizing twice is quantizing once."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf if isinstance(leaf, QTensor)
+        else fn(path, leaf),
+        params, is_leaf=lambda x: isinstance(x, QTensor))
+
+
 def quantize_model_params(params, policy: PrecisionPolicy = INT8):
     """Wrap every projection weight consumed by ``ops.quant_matmul`` in a
     ``QTensor``.  Leaves outside QUANT_SCOPES (embeddings, norms, MoE
     banks, SSM dynamics) pass through untouched, and so do weights that
-    are already ``QTensor``s: quantizing twice is quantizing once."""
+    are already ``QTensor``s."""
     if policy.weights != "int8":
         return params
+    return _map_params(
+        lambda path, leaf: (_leaf_qtensor(leaf) if _is_projection(path, leaf)
+                            else leaf), params)
 
-    def wrap(path, leaf):
-        if isinstance(leaf, QTensor):
-            return leaf
-        in_scope = any(getattr(k, "key", None) in QUANT_SCOPES
-                       for k in path)
-        if (in_scope and leaf.ndim >= 2
-                and jnp.issubdtype(leaf.dtype, jnp.floating)):
+
+def serving_params(params, policy: PrecisionPolicy, dtype):
+    """The at-rest form of the weights a server's steps read, made once.
+
+    Projections take the policy's form: ``QTensor`` at int8, ``dtype``
+    (the activation dtype) at float.  The ``embed`` / ``unembed`` tables
+    are held in ``dtype`` at either precision.  Every step rounds these
+    leaves to ``dtype`` before any use, so rounding them once here gives
+    the same bits and takes the per-step casts off the device.  Norms,
+    SSM dynamics and projections, MoE banks and routers, and ``QTensor``
+    scales keep their dtype.  Each leaf is converted in its own jitted
+    call, so no leaf is held twice in float32; the caller's tree is not
+    touched, and a leaf already in its at-rest form is returned as is.
+    """
+    dtype = jnp.dtype(dtype)
+
+    def at_rest(path, leaf):
+        if policy.weights == "int8" and _is_projection(path, leaf):
             return _leaf_qtensor(leaf)
+        table = len(path) == 1 and getattr(path[0], "key", None) in TABLES
+        if ((table or _is_projection(path, leaf))
+                and leaf.dtype != dtype):
+            return _leaf_cast(leaf, dtype)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(
-        wrap, params, is_leaf=lambda x: isinstance(x, QTensor))
+    return _map_params(at_rest, params)
 
 
 def attach_act_amax(qparams, amax_by_scope: Dict[str, float]):

@@ -26,11 +26,13 @@ Three schedulers over the same model serve steps:
   and decodes until its slowest member finishes; short requests block
   behind long ones.  Kept as the benchmark control.
 
-Both engines accept ``precision="float" | "int8"`` (paper C5 threaded
-end-to-end): int8 wraps projection weights in QTensor once at
-construction, serves through the quant-aware matmul entry point, and
-keeps the decode cache as Int8KV — ≥2× KV HBM, token-exact against the
-fake-quant float reference (docs/quantization.md).
+Every engine accepts ``precision="float" | "int8"`` (paper C5 threaded
+end-to-end) and puts the weights in their at-rest form once at
+construction (``quantize.serving_params``): float holds the projections
+and the embedding tables in the activation dtype, int8 wraps the
+projections in QTensor, serves through the quant-aware matmul entry
+point, and keeps the decode cache as Int8KV — ≥2× KV HBM, token-exact
+against the fake-quant float reference (docs/quantization.md).
 
 Both feed the decode step a per-slot ``kv_len`` — with pad-free
 admission this is the *exact* live fill (``position + 1``; 0 for idle or
@@ -59,7 +61,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.arch import ArchConfig
-from repro.core.quantize import policy_for, quantize_model_params
+from repro.core.quantize import policy_for, serving_params
 from repro.kernels.flash_decode import BLOCK_K, check_kv_block
 from repro.serve.kvcache import (BlockManager, PoolExhausted,
                                  alloc_decode_cache, alloc_paged_cache,
@@ -137,12 +139,22 @@ class _ServerBase:
         self.cfg = cfg
         self.precision = precision
         self.prec = policy_for(precision)
-        # int8: projection weights become QTensor leaves once, up front —
-        # the serving hot loop never sees a float weight again.
-        self.params = quantize_model_params(params, self.prec)
+        # the weights' at-rest form, made once: the steps never cast a
+        # weight, and no reference to the f32 leaves it replaced is kept
+        self.params = serving_params(params, self.prec, cfg.activation_dtype)
+        self._weight_bytes = sum(leaf.nbytes
+                                 for leaf in jax.tree.leaves(self.params))
         self._next_rid = 0
         self.requests: Dict[int, Request] = {}
         self.metrics: Dict[str, float] = {}
+
+    def _footprint_metrics(self) -> None:
+        """What every engine's ``run`` reports of its precision, chunk
+        and HBM: the KV cache and the params tree the steps read."""
+        self.metrics["precision"] = self.precision
+        self.metrics["prefill_chunk"] = self.chunk
+        self.metrics["kv_cache_bytes"] = decode_cache_nbytes(self.cache)
+        self.metrics["weight_bytes"] = self._weight_bytes
 
     def _slot_capacity(self) -> int:
         """Per-slot KV rows: prompt + generation budget, with headroom
@@ -429,9 +441,7 @@ class ContinuousBatchServer(_ServerBase):
                                   prefills=prefill_chunks,
                                   occupancy=occupancy,
                                   n_slots=self.n_slots)
-        self.metrics["precision"] = self.precision
-        self.metrics["prefill_chunk"] = self.chunk
-        self.metrics["kv_cache_bytes"] = decode_cache_nbytes(self.cache)
+        self._footprint_metrics()
         if kv_fill:
             # fraction of the slots × capacity rectangle the bounded
             # decode kernel reads per step (1.0 = no bounding).  Block-
@@ -541,9 +551,7 @@ class StaticBatchServer(_ServerBase):
         self.metrics = _summarize(served, wall, engine="static",
                                   decode_steps=decode_steps,
                                   prefills=prefill_chunks)
-        self.metrics["precision"] = self.precision
-        self.metrics["prefill_chunk"] = self.chunk
-        self.metrics["kv_cache_bytes"] = decode_cache_nbytes(self.cache)
+        self._footprint_metrics()
         return self.metrics
 
 
@@ -909,9 +917,7 @@ class PagedBatchServer(_ServerBase):
                                   prefills=prefill_chunks,
                                   occupancy=occupancy,
                                   n_slots=self.n_slots)
-        self.metrics["precision"] = self.precision
-        self.metrics["prefill_chunk"] = self.chunk
-        self.metrics["kv_cache_bytes"] = decode_cache_nbytes(self.cache)
+        self._footprint_metrics()
         self.metrics["kv_block_bytes"] = self._block_bytes
         self.metrics["block_size"] = self.block_size
         self.metrics["pool_blocks"] = self.pool_blocks

@@ -28,8 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs, flags
-from repro.core.quantize import (QTensor, policy_for,
-                                 quantize_model_params)
+from repro.core.quantize import policy_for, serving_params
 from repro.kernels import flash_decode as fd
 from repro.kernels import int8_matmul as im
 from repro.kernels import mamba_scan as ms
@@ -163,6 +162,13 @@ def test_mamba_scan_compiles(one_chip):
     _compile(ms.mamba_scan, x, x, bc, bc, _spec(s, (di, n), jnp.float32))
 
 
+def _served_params(cfg, prec):
+    """The abstract params tree a server holds (``serving_params``)."""
+    return jax.eval_shape(
+        lambda p: serving_params(p, prec, cfg.activation_dtype),
+        abstract_params(cfg))
+
+
 @pytest.mark.parametrize("precision", ["float", "int8"])
 def test_paged_steps_fit_one_chip(one_chip, precision, monkeypatch):
     """The full-width internlm2-1.8b paged decode and chunk steps, as the
@@ -177,8 +183,7 @@ def test_paged_steps_fit_one_chip(one_chip, precision, monkeypatch):
     def on_chip(tree):
         return jax.tree.map(lambda a: _spec(s, a.shape, a.dtype), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda p: quantize_model_params(p, prec), abstract_params(cfg)))
+    params = on_chip(_served_params(cfg, prec))
     cache = on_chip(abstract_paged_cache(cfg, SLOTS, CAPACITY, n_blocks,
                                          prec, BLOCK))
     vec = _spec(s, (SLOTS,), jnp.int32)
@@ -200,11 +205,12 @@ def test_paged_steps_fit_one_chip(one_chip, precision, monkeypatch):
 
 
 def _results(hlo_text):
-    """(name, opcode, shape) of every instruction in an HLO module."""
-    pat = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\]"
+    """(name, opcode, dtype, shape) of every instruction in an HLO
+    module, those inside fused computations included."""
+    pat = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\]"
                      r"(?:\{[^}]*\})? ([\w-]+)\(", re.M)
-    for name, dims, opcode in pat.findall(hlo_text):
-        yield name, opcode, tuple(int(x) for x in dims.split(",") if x)
+    for name, dtype, dims, opcode in pat.findall(hlo_text):
+        yield name, opcode, dtype, tuple(int(x) for x in dims.split(",") if x)
 
 
 @pytest.mark.parametrize("precision", ["float", "int8"])
@@ -213,9 +219,11 @@ def test_paged_steps_update_pool_in_place(one_chip, precision, monkeypatch):
     cell's pool, jitted with the cache donated as ``PagedBatchServer``
     builds them, update the pool in place: the layer scan carries the
     stacked pool and the kernels index its layer, so no whole-pool copy
-    or write-back and no per-layer pool slice appears, and the
-    temporaries hold no more than the bf16 casts of the float weights
-    and one pool leaf."""
+    or write-back and no per-layer pool slice appears.  The weights are
+    held as the server holds them (``serving_params``), so no step
+    converts a weight to bf16: no ``convert`` to bf16 yields a weight's
+    (K, N) matrix, a layer's slice or the whole stack; and the
+    temporaries hold less than one pool leaf."""
     monkeypatch.setitem(flags.FLAGS, "kernel_path", "pallas")
     s = one_chip
     cfg = configs.get("internlm2-1.8b")
@@ -225,8 +233,7 @@ def test_paged_steps_update_pool_in_place(one_chip, precision, monkeypatch):
     def on_chip(tree):
         return jax.tree.map(lambda a: _spec(s, a.shape, a.dtype), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda p: quantize_model_params(p, prec), abstract_params(cfg)))
+    params = on_chip(_served_params(cfg, prec))
     cache = on_chip(abstract_paged_cache(cfg, CELL_SLOTS, CELL_CAPACITY,
                                          CELL_BLOCKS, prec, BLOCK))
     vec = _spec(s, (CELL_SLOTS,), jnp.int32)
@@ -247,17 +254,16 @@ def test_paged_steps_update_pool_in_place(one_chip, precision, monkeypatch):
     pools = jax.tree.leaves({k: cache[k] for k in ("k", "v")})
     whole = {p.shape for p in pools}
     per_layer = {p.shape[1:] for p in pools}
-    floats = jax.tree.leaves(params,
-                             is_leaf=lambda x: isinstance(x, QTensor))
-    casts = sum(math.prod(w.shape) * 2 for w in floats
-                if not isinstance(w, QTensor)
-                and w.dtype == jnp.float32 and w.ndim >= 2)
+    matrices = {w.shape[-2:] for w in jax.tree.leaves(abstract_params(cfg))
+                if w.ndim >= 2}
     one_pool = max(math.prod(p.shape) * np.dtype(p.dtype).itemsize
                    for p in pools)
     for compiled in (decode, chunk):
         text = compiled.as_text()
         assert "tpu_custom_call" in text
-        for name, opcode, shape in _results(text):
+        for name, opcode, dtype, shape in _results(text):
+            assert not (opcode == "convert" and dtype == "bf16"
+                        and shape[-2:] in matrices), (name, shape)
             if shape in whole:
                 # copy-start/-done pairs that move the small f32 Int8KV
                 # scale pools to on-chip memory and back are the
@@ -269,4 +275,4 @@ def test_paged_steps_update_pool_in_place(one_chip, precision, monkeypatch):
             squeezed = tuple(shape[1:]) if shape[:1] == (1,) else shape
             assert squeezed not in per_layer, (name, shape)
         temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < casts + one_pool, (temp, casts, one_pool)
+        assert temp < one_pool, (temp, one_pool)
