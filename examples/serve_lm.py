@@ -1,6 +1,6 @@
 """Batched serving example: submit mixed-length prompts to the
-continuous-batching server (the EIM process-runner analogue, paper §4.6)
-and report TTFT / throughput.
+continuous-batching server, ``PagedBatchServer`` (the EIM
+process-runner analogue, paper §4.6), and report TTFT / throughput.
 
 Run:  PYTHONPATH=src python examples/serve_lm.py --arch zamba2-2.7b
 """
@@ -12,15 +12,13 @@ import numpy as np
 
 from repro import configs
 from repro.models.params import init_params
-from repro.serve.server import ContinuousBatchServer, StaticBatchServer
+from repro.serve.server import PagedBatchServer
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b",
                     choices=list(configs.ALIASES))
-    ap.add_argument("--engine", choices=("continuous", "static"),
-                    default="continuous")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", "--batch", dest="slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -31,15 +29,9 @@ def main():
 
     cfg = configs.get_smoke(args.arch)   # reduced config on CPU
     params = init_params(cfg, jax.random.key(0))
-    if args.engine == "static":
-        server = StaticBatchServer(cfg, params, batch_size=args.slots,
-                                   max_prompt=args.prompt_len,
-                                   max_new_tokens=args.max_new,
-                                   precision=args.precision)
-    else:
-        server = ContinuousBatchServer(
-            cfg, params, slots=args.slots, max_prompt=args.prompt_len,
-            max_new_tokens=args.max_new, precision=args.precision)
+    server = PagedBatchServer(
+        cfg, params, slots=args.slots, max_prompt=args.prompt_len,
+        max_new_tokens=args.max_new, precision=args.precision)
     rng = np.random.RandomState(0)
     # mixed-length workload: short and long prompts, varied budgets
     lens = [rng.randint(4, args.prompt_len + 1) for _ in range(args.requests)]

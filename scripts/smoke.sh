@@ -10,7 +10,7 @@ echo "=== quickstart (impulse train -> quantize -> estimate -> compile) ==="
 python examples/quickstart.py
 
 echo
-echo "=== serve bench (static vs continuous batching, tiny, float) ==="
+echo "=== serve bench (paged engine, tiny, float) ==="
 python benchmarks/serve_bench.py --tiny --precision float
 
 echo
@@ -33,17 +33,17 @@ echo "=== paged KV serving (block tables, prefix reuse, preemption) ==="
 # Paged-pool engine end-to-end through the Pallas interpreter, float AND
 # int8 in one run: a shared-prefix workload against a pool sized to
 # force preempt-and-recompute (the summary line reports preemptions ≥ 1,
-# prefix-hit rate, and live-KV HBM vs the contiguous rectangle);
-# token-exactness vs the contiguous engine is asserted inside the bench.
-# Pool blocks are the kernel's 128-row tile, so prompts and budgets are
-# long enough for a slot to span several blocks.
+# prefix-hit rate, and live-KV HBM vs the full pool); token-exactness
+# against the full pool is asserted inside the bench.  Pool blocks are
+# the kernel's 128-row tile, so prompts and budgets are long enough for
+# a slot to span several blocks.
 REPRO_KERNEL_PATH=interpret python benchmarks/serve_bench.py \
     --requests 6 --slots 3 --max-prompt 160 --max-new 120 \
-    --precision int8 --paged-only --pool-frac 0.34
+    --precision int8 --pool-frac 0.34
 
 echo
 echo "=== decode-kernel parity (Pallas lowering via interpret mode) ==="
 # Pin every kernels/ops dispatch to the Pallas interpreter so the
 # flash-decode lowering is exercised on every smoke run, not just on TPU:
-# kernel-vs-ref parity plus token-exact continuous serving through it.
+# kernel-vs-ref parity plus token-exact serving through it.
 REPRO_KERNEL_PATH=interpret python -m pytest -q tests/test_flash_decode.py

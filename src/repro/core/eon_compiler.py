@@ -102,50 +102,43 @@ def compile_impulse(impulse, batch_size: int = 1,
 
 
 def compile_serve_decode(cfg, params, *, slots: int, capacity: int,
-                         rules=None, mesh=None, policy=None,
-                         pool_blocks: Optional[int] = None,
-                         block_size: Optional[int] = None
+                         pool_blocks: int, rules=None, mesh=None,
+                         policy=None, block_size: Optional[int] = None
                          ) -> CompiledArtifact:
     """Serve-from-artifact hook (paper C4, end-to-end): AOT-compile the
-    continuous-batching decode step into a ``CompiledArtifact`` so the
-    server's hot loop runs the same kind of serialized executable we
-    "deploy" — zero Python tracing per token.
+    paged decode step into a ``CompiledArtifact`` so the server's hot
+    loop runs the same kind of serialized executable we "deploy" — zero
+    Python tracing per token.
 
     ``slots`` is the engine's decode batch (slot count), ``capacity`` the
-    per-slot KV row length (max prompt + max generation budget).
-    ``policy`` (``PrecisionPolicy``) lowers the int8 variant: QTensor
-    params and an Int8KV cache.  The artifact's static resource report
-    carries the KV-cache HBM footprint of both precisions so the deploy
-    decision can read the delta without compiling twice — Table 4's
-    RAM/flash story transposed to the serving tier.
+    per-slot logical KV length (max prompt + max generation budget), and
+    ``pool_blocks`` the physical blocks of the paged pool
+    (``kvcache.abstract_paged_cache``) of ``block_size`` rows (default
+    ``kv_block_size(capacity)``).  ``policy`` (``PrecisionPolicy``)
+    lowers the int8 variant: QTensor params and an Int8KV cache.
 
-    The decode signature is ``(params, cache, token, position, kv_len)``
-    — with pad-free chunked admission a cache row's index equals its
-    entry's absolute position, so the old separate ``write_idx`` operand
-    is gone; ``kv_len`` (slots,) is the scheduler's exact per-slot fill
-    (``position + 1``; 0 = idle or mid-prefill slot, whose row the step
-    neither reads nor writes).
+    The decode signature is ``(params, cache, token, position, kv_len,
+    block_table)``: with pad-free chunked admission a slot's logical KV
+    index equals its entry's absolute position, so there is no separate
+    write index; ``kv_len`` (slots,) is the scheduler's exact per-slot
+    fill (``position + 1``; 0 = idle or mid-prefill slot, whose row the
+    step neither reads nor writes) and ``block_table`` (slots,
+    capacity // BS) int32 maps each slot's logical blocks to pool blocks.
 
-    ``pool_blocks`` compiles the **paged** variant instead: the cache is
-    the paged pool (``kvcache.abstract_paged_cache``) and the signature
-    grows the per-slot block table — ``(params, cache, token, position,
-    kv_len, block_table)`` with ``block_table`` (slots, capacity // BS)
-    int32.  The resource report then prices the pool per block
-    (``kv_block_bytes``/``kv_pool_blocks``) so the deploy decision can
-    read live-KV HBM at any target occupancy, not just the worst case.
+    The artifact's static resource report carries the KV-cache HBM
+    footprint of both precisions, so the deploy decision can read the
+    delta without compiling twice — Table 4's RAM/flash story transposed
+    to the serving tier — and prices the pool per block
+    (``kv_block_bytes``/``kv_pool_blocks``) so live-KV HBM can be read at
+    any target occupancy, not just the worst case.
     """
-    from repro.serve.kvcache import (abstract_decode_cache,
-                                     abstract_paged_cache,
+    from repro.serve.kvcache import (abstract_paged_cache,
                                      decode_cache_nbytes, kv_block_size,
                                      kv_pool_block_bytes)
-    from repro.serve.serve_step import (make_paged_decode_step,
-                                        make_slot_decode_step)
+    from repro.serve.serve_step import make_paged_decode_step
 
-    paged = pool_blocks is not None
-    step = (make_paged_decode_step(cfg, rules=rules, mesh=mesh,
-                                   policy=policy) if paged
-            else make_slot_decode_step(cfg, rules=rules, mesh=mesh,
-                                       policy=policy))
+    step = make_paged_decode_step(cfg, rules=rules, mesh=mesh,
+                                  policy=policy)
     params_abs = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype),
         params)
@@ -153,31 +146,23 @@ def compile_serve_decode(cfg, params, *, slots: int, capacity: int,
     suffix = ""
     if policy is not None and policy.weights == "int8":
         suffix = "-int8"
-    if paged:
-        bs = block_size or kv_block_size(capacity)
-        cache_abs = abstract_paged_cache(cfg, slots, capacity,
-                                         pool_blocks, policy, bs)
-        table = jax.ShapeDtypeStruct((slots, capacity // bs), jnp.int32)
-        art = compile_fn(
-            step, params_abs, cache_abs, vec, vec, vec, table,
-            name=f"{cfg.name}-decode-b{slots}-s{capacity}"
-                 f"-paged{pool_blocks}x{bs}{suffix}")
-        art.memory["kv_block_bytes"] = kv_pool_block_bytes(cfg, capacity,
-                                                           policy, bs)
-        art.memory["kv_pool_blocks"] = pool_blocks
-    else:
-        cache_abs = abstract_decode_cache(cfg, slots, capacity, policy)
-        art = compile_fn(
-            step, params_abs, cache_abs, vec, vec, vec,
-            name=f"{cfg.name}-decode-b{slots}-s{capacity}{suffix}")
+    bs = block_size or kv_block_size(capacity)
+    cache_abs = abstract_paged_cache(cfg, slots, capacity, pool_blocks,
+                                     policy, bs)
+    table = jax.ShapeDtypeStruct((slots, capacity // bs), jnp.int32)
+    art = compile_fn(
+        step, params_abs, cache_abs, vec, vec, vec, table,
+        name=f"{cfg.name}-decode-b{slots}-s{capacity}"
+             f"-paged{pool_blocks}x{bs}{suffix}")
+    art.memory["kv_block_bytes"] = kv_pool_block_bytes(cfg, capacity,
+                                                       policy, bs)
+    art.memory["kv_pool_blocks"] = pool_blocks
     art.memory["kv_cache_bytes"] = decode_cache_nbytes(cache_abs)
     art.memory["kv_cache_bytes_float"] = (
         art.memory["kv_cache_bytes"] if suffix == ""
         else decode_cache_nbytes(
             abstract_paged_cache(cfg, slots, capacity, pool_blocks, None,
-                                 block_size)
-            if paged else abstract_decode_cache(cfg, slots, capacity,
-                                                None)))
+                                 bs)))
     art.memory["param_bytes"] = decode_cache_nbytes(params_abs)
     return art
 

@@ -1,14 +1,12 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id>``.
 
-Runs the continuous-batching server over synthetic prompts on the
+Runs ``PagedBatchServer`` (block tables, prefix sharing,
+preempt-and-recompute — docs/paged_kv.md) over synthetic prompts on the
 selected arch: its smoke config by default, its published widths with
-``--full`` (which needs the TPU: ``chip_smoke.py`` drives the paged
-engine that way on one v5e chip).  Kernels run through Pallas on a TPU
-backend and through the jnp references elsewhere.  ``--engine static``
-selects the static-batching baseline, ``--engine paged`` the
-paged-KV-pool engine (block tables, prefix
-sharing, preempt-and-recompute — docs/paged_kv.md; ``--pool-blocks``
-sizes the pool below the contiguous rectangle), ``--artifact`` runs the
+``--full`` (which needs the TPU: ``chip_smoke.py`` drives the engine
+that way on one v5e chip).  Kernels run through Pallas on a TPU backend
+and through the jnp references elsewhere.  ``--pool-blocks`` sizes the
+pool below the ``slots × capacity`` rectangle, ``--artifact`` runs the
 decode hot loop from an AOT ``CompiledArtifact`` (paper C4: serve the
 deployed executable).
 """
@@ -23,18 +21,15 @@ import numpy as np
 from repro import configs
 from repro.launch import compile_cache
 from repro.models.params import init_params
-from repro.serve.server import (ContinuousBatchServer, PagedBatchServer,
-                                StaticBatchServer)
+from repro.serve.server import PagedBatchServer
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--engine", choices=("continuous", "static", "paged"),
-                    default="continuous")
     ap.add_argument("--pool-blocks", type=int, default=None,
-                    help="paged engine: physical KV blocks in the pool"
-                         " (default: the contiguous rectangle's count)")
+                    help="physical KV blocks in the pool (default: the"
+                         " slots × capacity rectangle's count)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", "--batch", dest="slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -54,24 +49,11 @@ def main() -> None:
 
     cfg = configs.get(args.arch) if args.full else configs.get_smoke(args.arch)
     params = init_params(cfg, jax.random.key(0))
-    if args.engine == "static":
-        server = StaticBatchServer(cfg, params, batch_size=args.slots,
-                                   max_prompt=args.prompt_len,
-                                   prefill_chunk=args.prefill_chunk,
-                                   max_new_tokens=args.max_new,
-                                   precision=args.precision)
-    elif args.engine == "paged":
-        server = PagedBatchServer(
-            cfg, params, slots=args.slots, max_prompt=args.prompt_len,
-            prefill_chunk=args.prefill_chunk,
-            max_new_tokens=args.max_new, use_artifact=args.artifact,
-            pool_blocks=args.pool_blocks, precision=args.precision)
-    else:
-        server = ContinuousBatchServer(
-            cfg, params, slots=args.slots, max_prompt=args.prompt_len,
-            prefill_chunk=args.prefill_chunk,
-            max_new_tokens=args.max_new, use_artifact=args.artifact,
-            precision=args.precision)
+    server = PagedBatchServer(
+        cfg, params, slots=args.slots, max_prompt=args.prompt_len,
+        prefill_chunk=args.prefill_chunk,
+        max_new_tokens=args.max_new, use_artifact=args.artifact,
+        pool_blocks=args.pool_blocks, precision=args.precision)
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, size=args.prompt_len)
                .astype(np.int32) for _ in range(args.requests)]

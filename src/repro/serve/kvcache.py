@@ -1,6 +1,6 @@
-"""KV-cache utilities: sizing arithmetic, the slot API the continuous-
-batching engine is built on, and the **paged KV pool** (block table +
-``BlockManager``) the paged engine is built on.
+"""KV-cache utilities: sizing arithmetic, the slot-view API, and the
+**paged KV pool** (block table + ``BlockManager``) the serving engine is
+built on.
 
 Cache construction lives with the model (transformer._cache_from_prefill)
 so layouts stay next to the attention code; this module adds the
@@ -8,21 +8,22 @@ serving-side pieces:
 
 * ``kv_cache_bytes``        — footprint arithmetic (estimator/server).
 * ``kv_block_size``         — the KV block granularity (canonical home;
-                              the kernels, both engines' capacity
+                              the kernels, the server's capacity
                               rounding, and the paged pool's physical
                               block size all share this one helper).
-* ``alloc_decode_cache``    — zero-filled slot-addressed decode cache of
+* ``alloc_decode_cache``    — zero-filled unpaged decode cache of
                               ``slots`` rows × ``capacity`` KV entries,
-                              position arrays initialised to -1 (invalid).
-* ``slot_batch_axes`` / ``take_slot`` / ``put_slot`` — the slot-view API
-  chunked pad-free prefill is built on: slice one slot's row out of the
-  big cache (a batch-1 sub-cache), run a prefill chunk against it, and
-  splice it back.  Admission resets a slot by ``put_slot``-ing an empty
-  batch-1 cache in (positions −1, SSM state zeroed).
-* ``release_slot``          — invalidate a slot row's positions so stale
-                              KV can never be attended (the free path).
-* ``abstract_decode_cache`` — ShapeDtypeStructs of the above, for AOT
-                              export (eon_compiler.compile_serve_decode).
+                              position arrays initialised to -1 (invalid);
+                              the paged layout is derived from it, and
+                              admission splices its batch-1 form in to
+                              reset a slot's slot-addressed leaves.
+* ``abstract_decode_cache`` — ShapeDtypeStructs of the above.
+* ``paged_slot_axes`` / ``take_slot`` / ``put_slot`` — the slot-view API
+  chunked pad-free prefill is built on: slice one slot's slot-addressed
+  leaves out of the cache (a batch-1 sub-cache, pool leaves passed
+  whole), run a prefill chunk against it, and splice it back.
+  Admission resets a slot by ``put_slot``-ing an empty batch-1 cache in
+  (positions −1, SSM state zeroed).
 
 Paged layout (docs/paged_kv.md): the full-attention KV leaves trade
 their per-slot ``capacity`` rectangle for a global pool of ``num_blocks``
@@ -37,14 +38,13 @@ and LRU reclaim of cached-but-unreferenced blocks; preempt-and-recompute
 lives in the scheduler/server on top of it.
 
 Validity is decided by stored positions (−1 = empty) plus the
-scheduler's per-slot ``kv_len`` bound, so a slot row can be recycled
-between decode steps without touching the K/V bytes — and, in the paged
-layout, so a physical block can be handed to a new tenant without being
-scrubbed (the new tenant's writes precede its ``kv_len``).
+scheduler's per-slot ``kv_len`` bound, so a physical block can be
+handed to a new tenant without being scrubbed (the new tenant's writes
+precede its ``kv_len``).
 
 Every entry point is precision-aware (``PrecisionPolicy``): an int8
 policy makes the KV leaves ``Int8KV`` pairs — int8 values plus one f32
-scale per (entry, head) — and the slot API splices/releases/sizes the
+scale per (entry, head) — and the slot API splices and sizes the
 paired pytree; ``decode_cache_nbytes`` measures the HBM delta.
 """
 from __future__ import annotations
@@ -108,7 +108,7 @@ def kv_cache_bytes(cfg: ArchConfig, batch: int, seq_len: int,
 
 
 # ---------------------------------------------------------------------------
-# Slot-addressed decode cache (continuous batching)
+# Unpaged decode cache and the slot-view API
 # ---------------------------------------------------------------------------
 def abstract_decode_cache(cfg: ArchConfig, slots: int, capacity: int,
                           policy: Optional[PrecisionPolicy] = None):
@@ -154,24 +154,11 @@ def _first_diff_axis(big_shape, small_shape) -> int:
     return -1  # identical shapes: slots == 1, write in place
 
 
-def slot_batch_axes(cfg: ArchConfig, slots: int, capacity: int,
-                    policy: Optional[PrecisionPolicy] = None):
-    """Per-leaf batch-axis pytree of the decode cache, inferred by
-    diffing the ``slots``-row abstract cache against its batch-1 twin —
-    robust to every layout (stacked-layer KV, Int8KV value/scale pairs,
-    nested SSM state).  Computed once per server; closed over (static)
-    by the jitted slot-view steps.  −1 marks a leaf with no batch axis
-    (only possible when ``slots == 1``: slice/splice in place)."""
-    big = abstract_decode_cache(cfg, slots, capacity, policy)
-    small = abstract_decode_cache(cfg, 1, capacity, policy)
-    return jax.tree.map(lambda b, s: _first_diff_axis(b.shape, s.shape),
-                        big, small)
-
-
 def take_slot(big_cache, axes, slot):
     """Slice slot ``slot``'s row out of the big cache as a batch-1
-    sub-cache (``axes`` from ``slot_batch_axes``, closed over — the axis
-    choice must be static under jit; ``slot`` may be traced)."""
+    sub-cache (``axes`` from ``paged_slot_axes``, closed over — the axis
+    choice must be static under jit; ``slot`` may be traced).  A leaf
+    whose axis is −1 is taken whole."""
     def take(big, axis):
         if axis < 0:
             return big
@@ -194,20 +181,6 @@ def put_slot(big_cache, small_cache, axes, slot):
         return lax.dynamic_update_slice(big, small.astype(big.dtype),
                                         tuple(starts))
     return jax.tree.map(put, big_cache, small_cache, axes)
-
-
-def release_slot(big_cache: Dict[str, Any], slot) -> Dict[str, Any]:
-    """Invalidate a slot row: set its position entries to −1.  K/V bytes
-    stay in place — they are unreachable once no position marks them.
-    (``pool_pos`` is pool-addressed, not per-slot, and is skipped: paged
-    reuse is fenced by ``kv_len``, not by scrubbing — see
-    docs/paged_kv.md.)"""
-    out = dict(big_cache)
-    for key, big in big_cache.items():
-        if key.endswith("_pos") and key != "pool_pos":
-            row = jnp.full((1, big.shape[1]), -1, big.dtype)
-            out[key] = lax.dynamic_update_slice(big, row, (slot, 0))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -275,9 +248,12 @@ def paged_slot_axes(cfg: ArchConfig, slots: int, capacity: int,
                     block_size: Optional[int] = None):
     """Per-leaf batch-axis pytree for the *paged* cache, consumed by
     ``take_slot``/``put_slot``: slot-addressed leaves carry their batch
-    axis as in ``slot_batch_axes``; pool leaves (and ``pool_pos``) carry
-    −1 — "no slot axis", which those helpers already treat as take-whole
-    / splice-whole, exactly what a globally shared pool needs."""
+    axis, inferred by diffing each leaf against its batch-1 twin — robust
+    to every layout (Int8KV value/scale pairs, nested SSM state); pool
+    leaves (and ``pool_pos``) carry −1 — "no slot axis", which those
+    helpers treat as take-whole / splice-whole, exactly what a globally
+    shared pool needs.  Computed once per server and closed over
+    (static) by the jitted slot-view steps."""
     cache = abstract_paged_cache(cfg, slots, capacity, num_blocks, policy,
                                  block_size)
     small = abstract_decode_cache(cfg, 1, capacity, policy)

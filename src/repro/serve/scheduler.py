@@ -1,22 +1,23 @@
-"""Slot scheduler for the continuous-batching engines (paper §4.6).
+"""Slot scheduler for the continuous-batching engine (paper §4.6).
 
 The serving analogue of the EIM process runner's queue: requests wait in
 an FCFS queue; a fixed set of KV-cache *slots* is the unit of admission.
 A slot's lifecycle is
 
     FREE ──admit──▶ PREFILLING ──last chunk──▶ ACTIVE ──finish──▶ FREE
-         (reset_slot)   (chunk steps,        (decode steps)  (release_slot)
-                         budgeted per            │
-                         decode step)            │ pool dry (paged)
+         (reset slot    (chunk steps,        (decode steps)  (blocks
+          leaves)        budgeted per            │            freed)
+                         decode step)            │ pool dry
                                                  ▼
                                             PREEMPTED ──▶ back to queue
                                             (blocks freed; re-admitted
                                              FCFS-front and re-prefilled
                                              over prompt ++ generated)
 
-Admission is cheap (host bookkeeping plus one device-side slot-row
-reset — no prefill compute): the prompt is then consumed in fixed-size
-chunks *interleaved with decode steps* under a per-step token budget,
+Admission is cheap (host bookkeeping plus, where the family has
+slot-addressed leaves, one device-side slot reset — no prefill
+compute): the prompt is then consumed in fixed-size chunks
+*interleaved with decode steps* under a per-step token budget,
 each chunk written unpadded into the slot's cache rows — no pad row
 ever occupies KV capacity, and a long prompt can never
 head-of-line-block the active slots' next tokens.  Slots are freed
@@ -24,8 +25,8 @@ head-of-line-block the active slots' next tokens.  Slots are freed
 never waits for the longest member of its batch — that is the whole
 difference between continuous and static batching.
 
-Under the **paged** engine the admission gate is the free-block
-watermark of the KV pool, not merely a free slot: a request is admitted
+The admission gate is the free-block watermark of the paged KV pool
+(the server's ``_admit``), not merely a free slot: a request is admitted
 only when the pool covers its prompt's blocks (minus any prefix-cached
 blocks it can share), and when the pool later runs dry mid-decode the
 *youngest* slot is PREEMPTED — its blocks freed, its request re-queued
@@ -40,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Deque, List, Optional, Tuple
+from typing import Deque, List, Optional
 
 import numpy as np
 
@@ -63,10 +64,9 @@ class Slot:
     position: int = 0              # absolute position of the next token
     generated: int = 0             # tokens emitted for this request
     max_new: int = 0
-    # paged engine only: physical KV block ids in logical order — the
-    # host mirror of this slot's block-table row (prefix-shared blocks,
-    # which carry extra refcounts, sit at the front; `chunk_pos` starts
-    # past them).
+    # physical KV block ids in logical order — the host mirror of this
+    # slot's block-table row (prefix-shared blocks, which carry extra
+    # refcounts, sit at the front; `chunk_pos` starts past them).
     blocks: List[int] = dataclasses.field(default_factory=list)
 
     @property
@@ -135,15 +135,6 @@ class SlotScheduler:
 
     def active_slots(self) -> List[Slot]:
         return [s for s in self.slots if s.active]
-
-    def admissions(self) -> List[Tuple[Slot, object]]:
-        """Pair waiting requests with free slots (drains either side)."""
-        out = []
-        for slot in self.free_slots():
-            if not self.waiting:
-                break
-            out.append((slot, self.waiting.popleft()))
-        return out
 
     def requeue_front(self, req) -> None:
         """PREEMPTED re-entry: a preempted request outranks every

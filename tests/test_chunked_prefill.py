@@ -34,7 +34,7 @@ from repro.models.params import init_params
 from repro.models.transformer import grow_cache
 from repro.serve.kvcache import alloc_decode_cache
 from repro.serve.scheduler import SlotScheduler
-from repro.serve.server import ContinuousBatchServer
+from repro.serve.server import PagedBatchServer
 
 
 @functools.lru_cache(maxsize=None)
@@ -320,8 +320,8 @@ def test_chunked_serving_token_exact_float(arch, chunk):
     SSM/hybrid rows are the previously-caveated scenario, now exact."""
     cfg, params = _setup(arch)
     prompts = _workload(cfg)
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=chunk, max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           prefill_chunk=chunk, max_new_tokens=8)
     reqs = srv.submit(prompts, max_new_tokens=list(_BUDGETS))
     srv.run()
     for r, ref in zip(reqs, _references(arch)):
@@ -340,11 +340,10 @@ def test_chunked_serving_token_exact_int8(arch, chunk):
     prompts = _workload(cfg, seed=6)
     kw = dict(slots=2, max_prompt=16, prefill_chunk=chunk,
               max_new_tokens=8)
-    srv = ContinuousBatchServer(cfg, params, precision="int8", **kw)
+    srv = PagedBatchServer(cfg, params, precision="int8", **kw)
     reqs = srv.submit(prompts, max_new_tokens=list(_BUDGETS))
     srv.run()
-    fq = ContinuousBatchServer(cfg, params, precision="int8_fakequant",
-                               **kw)
+    fq = PagedBatchServer(cfg, params, precision="int8_fakequant", **kw)
     freqs = fq.submit(prompts, max_new_tokens=list(_BUDGETS))
     fq.run()
     assert [r.tokens for r in reqs] == [r.tokens for r in freqs], \
@@ -378,9 +377,9 @@ def test_long_prefill_does_not_starve_decode():
     rng = np.random.RandomState(7)
     short = rng.randint(0, cfg.vocab_size, 4).astype(np.int32)
     long = rng.randint(0, cfg.vocab_size, 20).astype(np.int32)
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=24,
-                                prefill_chunk=4, prefill_token_budget=4,
-                                max_new_tokens=10)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=24,
+                           prefill_chunk=4, prefill_token_budget=4,
+                           max_new_tokens=10)
     ra, rb = srv.submit([short, long], max_new_tokens=[10, 6])
     srv.run()
     # short request decoded every step: 1 prefill token + 9 decode steps

@@ -31,7 +31,7 @@ from repro.kernels import ops
 from repro.models import api
 from repro.models.params import init_params
 from repro.models.transformer import grow_cache
-from repro.serve.server import ContinuousBatchServer
+from repro.serve.server import PagedBatchServer
 
 
 # ---------------------------------------------------------------------------
@@ -180,8 +180,8 @@ def test_continuous_serving_interpret_float(arch, interpret_path):
     lens, budgets = [3, 9, 6], [4, 3, 5]
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in lens]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=4, max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           prefill_chunk=4, max_new_tokens=8)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     srv.run()
     for r, p, bud in zip(reqs, prompts, budgets):
@@ -202,14 +202,14 @@ def test_continuous_serving_interpret_int8_vs_fakequant(arch,
     lens, budgets = [3, 8, 5], [4, 3, 5]
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in lens]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=4, max_new_tokens=8,
-                                precision="int8")
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           prefill_chunk=4, max_new_tokens=8,
+                           precision="int8")
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     srv.run()
-    oracle = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                   prefill_chunk=4, max_new_tokens=8,
-                                   precision="int8_fakequant")
+    oracle = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                              prefill_chunk=4, max_new_tokens=8,
+                              precision="int8_fakequant")
     oreqs = oracle.submit(prompts, max_new_tokens=budgets)
     oracle.run()
     assert [r.tokens for r in reqs] == [r.tokens for r in oreqs], \
@@ -233,8 +233,8 @@ def test_int8_decode_never_dequantizes_cache(monkeypatch):
     rng = np.random.RandomState(7)
     prompts = [rng.randint(0, cfg.vocab_size, 5).astype(np.int32)
                for _ in range(2)]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=8,
-                                max_new_tokens=4, precision="int8")
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                           max_new_tokens=4, precision="int8")
     srv.submit(prompts)
     srv.run()
     assert calls == [], \

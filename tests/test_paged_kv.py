@@ -41,7 +41,7 @@ from repro.models.transformer import grow_cache
 from repro.serve.kvcache import (BlockManager, PoolExhausted,
                                  abstract_paged_cache, kv_block_size,
                                  kv_pool_block_bytes, paged_cache_keys)
-from repro.serve.server import ContinuousBatchServer, PagedBatchServer
+from repro.serve.server import PagedBatchServer
 
 
 @functools.lru_cache(maxsize=None)
@@ -76,9 +76,6 @@ def test_kv_block_size_is_shared():
                       (72, 72), (144, 16), (8, 8)):
         assert kv_block_size(cap) == want, cap
     cfg, params = _setup("internlm2-1.8b")
-    srv = ContinuousBatchServer(cfg, params, slots=1, max_prompt=16,
-                                max_new_tokens=4)
-    assert srv._kv_block == kv_block_size(srv.capacity)
     psrv = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
                             max_new_tokens=4)
     assert psrv.block_size == kv_block_size(psrv.capacity)

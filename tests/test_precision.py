@@ -20,8 +20,9 @@ from repro.kernels import ops, ref
 from repro.models import api
 from repro.models.params import init_params
 from repro.models.transformer import grow_cache
-from repro.serve.kvcache import alloc_decode_cache, decode_cache_nbytes
-from repro.serve.server import ContinuousBatchServer, StaticBatchServer
+from repro.serve.kvcache import (alloc_decode_cache, alloc_paged_cache,
+                                 decode_cache_nbytes)
+from repro.serve.server import PagedBatchServer
 
 ARCH = "internlm2-1.8b"
 
@@ -193,46 +194,79 @@ def test_kv_quant_roundtrip_and_policy_modes():
 
 
 # ---------------------------------------------------------------------------
-# Serving: token-exact int8 vs fake-quant float reference (acceptance)
+# Serving: int8 vs the fake-quant float simulation (acceptance)
 # ---------------------------------------------------------------------------
 def _fake_quant_reference(cfg, qparams, prompt, max_new):
-    """Greedy contiguous decode of the float fake-quant simulation — the
-    oracle the native int8 path must reproduce token-exactly."""
+    """Greedy one-shot decode of the float fake-quant simulation (the
+    whole prompt in one prefill, then one token per step): its tokens
+    and, per step, the logits each was chosen from."""
     pol = qz.INT8_FAKEQUANT
     fns = api.model_fns(cfg)
     logits, cache = fns.forward_prefill(
         cfg, qparams, {"tokens": jnp.asarray(prompt[None, :])}, pol)
     cache = grow_cache(cfg, cache, max_new + 1)
-    out = [int(jnp.argmax(logits, -1)[0])]
+    steps = [np.asarray(logits[0])]
+    out = [int(np.argmax(steps[-1]))]
     pos = len(prompt)
     for _ in range(max_new - 1):
         logits, cache = fns.forward_decode(
             cfg, qparams, cache, jnp.asarray([out[-1]], jnp.int32),
             jnp.asarray([pos], jnp.int32), policy=pol)
-        out.append(int(jnp.argmax(logits, -1)[0]))
+        steps.append(np.asarray(logits[0]))
+        out.append(int(np.argmax(steps[-1])))
         pos += 1
-    return out
+    return out, steps
+
+
+# Near-tie margin for the one-shot reference.  A one-shot prefill attends
+# over the prompt's float K/V; a chunked prefill attends over the int8
+# cache it has just written, as every decode step does.  On this test's
+# model and workload that moves the logits by at most 4.28e-3 (max
+# |logit difference| between the served int8 path and the one-shot int8
+# path fed the served tokens, over all 15 steps, CPU; with the prompt's
+# K/V quantized inside the one-shot attention it falls to 1.6e-7).  A
+# top-1/top-2 margin can move by twice that, 8.6e-3, so below TAU the
+# reference's greedy choice is a tie the served path may break either way.
+TAU = 1e-2
 
 
 def test_int8_serving_token_exact(setup):
-    """Continuous int8 serving (chunked pad-free prefill, slot-recycled
-    Int8KV cache, ref kernel path) == fake-quant float reference."""
+    """Int8 serving (chunked pad-free prefill, paged Int8KV cache, ref
+    kernel path) is token-exact against the fake-quant engine on the same
+    schedule, and follows the one-shot fake-quant reference at every step
+    whose reference margin is at least ``TAU``; at the first step below
+    it the served token is one of the reference's top two, after which
+    the two streams may part."""
     cfg, params = setup
     rng = np.random.RandomState(4)
     lens = [3, 11, 7]
     budgets = [5, 4, 6]
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in lens]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=4, max_new_tokens=8,
-                                precision="int8")
+    kw = dict(slots=2, max_prompt=16, prefill_chunk=4, max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, precision="int8", **kw)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     m = srv.run()
     assert m["precision"] == "int8"
+    fq = PagedBatchServer(cfg, params, precision="int8_fakequant", **kw)
+    freqs = fq.submit(prompts, max_new_tokens=budgets)
+    fq.run()
+    assert [r.tokens for r in reqs] == [r.tokens for r in freqs], \
+        "int8 serving diverged from the fake-quant engine"
     qparams = qz.quantize_model_params(params, qz.INT8)
     for r, p, b in zip(reqs, prompts, budgets):
-        assert r.tokens == _fake_quant_reference(cfg, qparams, p, b), \
-            f"rid {r.rid}: int8 serving diverged from fake-quant reference"
+        assert len(r.tokens) == b
+        want, steps = _fake_quant_reference(cfg, qparams, p, b)
+        for i, (got, ref_tok, lg) in enumerate(zip(r.tokens, want, steps)):
+            top2 = np.argsort(lg)[::-1][:2]
+            margin = float(lg[top2[0]] - lg[top2[1]])
+            if margin < TAU:
+                assert got in top2, \
+                    f"rid {r.rid} step {i}: {got} not in top two {top2}"
+                break
+            assert got == ref_tok, \
+                (f"rid {r.rid} step {i}: served {got}, reference"
+                 f" {ref_tok} at margin {margin:.2e}")
     # quantization is real at the numeric level: int8 logits differ from
     # float logits (greedy tokens may still coincide on a smoke model)
     fns = api.model_fns(cfg)
@@ -244,22 +278,23 @@ def test_int8_serving_token_exact(setup):
 
 
 def test_static_and_continuous_agree_int8(setup):
-    """Scheduling still never changes tokens — now at int8."""
+    """Scheduling still never changes tokens — now at int8: one slot
+    serves the requests one at a time, two slots batch and recycle."""
     cfg, params = setup
     rng = np.random.RandomState(5)
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in (4, 9, 6)]
     budgets = [3, 5, 2]
-    stat = StaticBatchServer(cfg, params, batch_size=2, max_prompt=16,
-                             max_new_tokens=8, precision="int8")
-    sreqs = stat.submit(prompts, max_new_tokens=budgets)
-    ms = stat.run()
-    cont = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                 max_new_tokens=8, precision="int8")
-    creqs = cont.submit(prompts, max_new_tokens=budgets)
-    cont.run()
-    assert [r.tokens for r in sreqs] == [r.tokens for r in creqs]
-    assert ms["precision"] == "int8"
+    one = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
+                           max_new_tokens=8, precision="int8")
+    oreqs = one.submit(prompts, max_new_tokens=budgets)
+    mo = one.run()
+    two = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           max_new_tokens=8, precision="int8")
+    treqs = two.submit(prompts, max_new_tokens=budgets)
+    two.run()
+    assert [r.tokens for r in oreqs] == [r.tokens for r in treqs]
+    assert mo["precision"] == "int8"
 
 
 # ---------------------------------------------------------------------------
@@ -296,16 +331,20 @@ def test_compile_serve_decode_int8_reports_hbm_delta(setup):
     from repro.core.eon_compiler import compile_serve_decode
     cfg, params = setup
     qparams = qz.quantize_model_params(params, qz.INT8)
-    art = compile_serve_decode(cfg, qparams, slots=2, capacity=12,
+    art = compile_serve_decode(cfg, qparams, slots=2, capacity=16,
+                               pool_blocks=3, block_size=8,
                                policy=qz.INT8)
     assert art.name.endswith("-int8")
     mem = art.memory
     assert mem["kv_cache_bytes_float"] / mem["kv_cache_bytes"] >= 2.0
+    assert mem["kv_pool_blocks"] == 3 and mem["kv_block_bytes"] > 0
     # the serialized executable stays runnable; decode signature is
-    # (params, cache, token, position, kv_len) — index == position under
-    # pad-free admission, so there is no separate write_idx operand
+    # (params, cache, token, position, kv_len, block_table) — index ==
+    # position under pad-free admission, so there is no separate
+    # write_idx operand; the table maps 16 logical rows to 2 pool blocks
     fn = art.rehydrate()
-    cache = alloc_decode_cache(cfg, 2, 12, qz.INT8)
+    cache = alloc_paged_cache(cfg, 2, 16, 3, qz.INT8, 8)
     tok = jnp.zeros((2,), jnp.int32)
-    ntok, _, _ = fn(qparams, cache, tok, tok, tok)
+    table = jnp.asarray([[0, 1], [2, 0]], jnp.int32)
+    ntok, _, _ = fn(qparams, cache, tok, tok, tok, table)
     assert ntok.shape == (2,)

@@ -1,4 +1,5 @@
-"""Continuous-batching serving engine tests (paper §4.6).
+"""Continuous-batching serving engine tests (paper §4.6), on
+``PagedBatchServer``.
 
 Covers the scheduler invariants the engine is built on: slot recycling
 admits queued work before the batch drains, per-request budgets are
@@ -12,14 +13,14 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.core import quantize as qz
 from repro.models import api
 from repro.models.params import init_params
 from repro.models.transformer import grow_cache
-from repro.serve.kvcache import (alloc_decode_cache, put_slot,
-                                 release_slot, slot_batch_axes, take_slot)
-from repro.serve.scheduler import Slot, SlotScheduler
-from repro.serve.server import (ContinuousBatchServer, StaticBatchServer,
-                                _chunk_rows)
+from repro.serve.kvcache import (alloc_paged_cache, paged_slot_axes,
+                                 put_slot, take_slot)
+from repro.serve.scheduler import Slot
+from repro.serve.server import PagedBatchServer, _chunk_rows
 
 ARCH = "internlm2-1.8b"
 
@@ -68,16 +69,21 @@ def test_slot_lifecycle():
     assert s.free and s.prompt is None
 
 
-def test_slot_scheduler_fcfs():
-    s = SlotScheduler(2)
-    s.enqueue("a"), s.enqueue("b"), s.enqueue("c")
-    adm = s.admissions()
-    assert [r for _, r in adm] == ["a", "b"]
-    for slot, _ in adm:
-        slot.occupy(rid=1, prompt=np.arange(4, dtype=np.int32), max_new=4)
-    assert s.admissions() == []      # no free slot for "c"
-    adm[0][0].release()
-    assert [r for _, r in s.admissions()] == ["c"]
+def test_slot_scheduler_fcfs(setup):
+    """The engine's admission fills free slots in FCFS order, and a
+    request with no free slot waits at the queue head until one frees."""
+    cfg, params = setup
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                           max_new_tokens=4)
+    a, b, c = srv.submit([np.arange(4, dtype=np.int32)] * 3)
+    srv._admit(0)
+    assert [s.rid for s in srv.sched.slots] == [a.rid, b.rid]
+    assert [r.rid for r in srv.sched.waiting] == [c.rid]
+    srv._admit(0)                    # no free slot for "c"
+    assert [r.rid for r in srv.sched.waiting] == [c.rid]
+    srv._free_slot(srv.sched.slots[0])
+    srv._admit(0)
+    assert srv.sched.slots[0].rid == c.rid and not srv.sched.waiting
 
 
 def test_chunk_rows():
@@ -92,8 +98,8 @@ def test_over_capacity_prompt_errors(setup):
     submit (the old bucket policy kept the most recent tokens and
     silently dropped the rest)."""
     cfg, params = setup
-    srv = ContinuousBatchServer(cfg, params, slots=1, max_prompt=16,
-                                max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
+                           max_new_tokens=8)
     rng = np.random.RandomState(0)
     with pytest.raises(ValueError, match="cache rows"):
         srv.submit([rng.randint(0, cfg.vocab_size, 200).astype(np.int32)])
@@ -118,8 +124,8 @@ def test_slot_recycling_admits_before_drain(setup):
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, 6).astype(np.int32)
                for _ in range(3)]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=8,
-                                prefill_chunk=8, max_new_tokens=12)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                           prefill_chunk=8, max_new_tokens=12)
     # slot 0 finishes early (2 tokens), slot 1 runs long (12); request 3
     # must start before request 2 finishes.
     r1, r2, r3 = srv.submit(prompts, max_new_tokens=[2, 12, 6])
@@ -138,8 +144,8 @@ def test_per_request_max_new_honored(setup):
     budgets = [1, 3, 7, 5]
     prompts = [rng.randint(0, cfg.vocab_size, 5).astype(np.int32)
                for _ in budgets]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=8,
-                                max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                           max_new_tokens=8)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     m = srv.run()
     assert [len(r.tokens) for r in reqs] == budgets
@@ -155,8 +161,8 @@ def test_chunked_prefill_matches_reference(setup):
     budgets = [5, 4, 6, 3]
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in lens]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=4, max_new_tokens=8)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           prefill_chunk=4, max_new_tokens=8)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     srv.run()
     for r, p, b in zip(reqs, prompts, budgets):
@@ -165,21 +171,22 @@ def test_chunked_prefill_matches_reference(setup):
 
 
 def test_static_and_continuous_agree(setup):
-    """Scheduling must not change the tokens, only the latency."""
+    """Scheduling must not change the tokens, only the latency: one slot
+    serves the requests one at a time, two slots batch and recycle."""
     cfg, params = setup
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in (4, 9, 12, 6)]
     budgets = [3, 6, 2, 5]
-    stat = StaticBatchServer(cfg, params, batch_size=2, max_prompt=16,
-                             max_new_tokens=8)
-    sreqs = stat.submit(prompts, max_new_tokens=budgets)
-    stat.run()
-    cont = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                 max_new_tokens=8)
-    creqs = cont.submit(prompts, max_new_tokens=budgets)
-    cont.run()
-    assert [r.tokens for r in sreqs] == [r.tokens for r in creqs]
+    one = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
+                           max_new_tokens=8)
+    oreqs = one.submit(prompts, max_new_tokens=budgets)
+    one.run()
+    two = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           max_new_tokens=8)
+    treqs = two.submit(prompts, max_new_tokens=budgets)
+    two.run()
+    assert [r.tokens for r in oreqs] == [r.tokens for r in treqs]
 
 
 def test_metrics_sanity(setup):
@@ -187,8 +194,8 @@ def test_metrics_sanity(setup):
     rng = np.random.RandomState(4)
     prompts = [rng.randint(0, cfg.vocab_size, 6).astype(np.int32)
                for _ in range(4)]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=8,
-                                max_new_tokens=4)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                           max_new_tokens=4)
     reqs = srv.submit(prompts)
     m = srv.run()
     assert m["requests"] == 4
@@ -206,36 +213,59 @@ def test_metrics_sanity(setup):
     assert firsts == sorted(firsts)
 
 
-def test_slot_view_isolated(setup):
-    """take_slot/put_slot touch exactly one row; release_slot
-    invalidates it (positions only — K/V bytes stay unreachable)."""
-    cfg, params = setup
-    axes = slot_batch_axes(cfg, 3, 12)
-    cache = alloc_decode_cache(cfg, slots=3, capacity=12)
-    assert np.all(np.asarray(cache["full_pos"]) == -1)
-    # run one chunk into slot 1's view and splice it back
-    fns = api.model_fns(cfg)
+# the paged cache's slot view: 3 slots of 16 logical rows over a pool
+# of 6 blocks of 8; slot 1's table row maps its first block to block 4
+_VIEW = dict(slots=3, capacity=16, num_blocks=6, block_size=8)
+
+
+def _arr(leaf):
+    return np.asarray(leaf.q if isinstance(leaf, qz.Int8KV) else leaf)
+
+
+def _chunk_into_slot1(cfg, params, policy=None):
+    """One 8-token chunk run in slot 1's view of an empty paged cache
+    and spliced back; returns (cache, cache after the splice)."""
+    axes = paged_slot_axes(cfg, policy=policy, **_VIEW)
+    cache = alloc_paged_cache(cfg, policy=policy, **_VIEW)
     small = take_slot(cache, axes, 1)
+    for key in ("global_k", "pool_pos"):      # pool leaves: taken whole
+        assert _arr(small[key]).shape == _arr(cache[key]).shape, key
+    assert _arr(small["local_k"]).shape[2] == 1
+    fns = api.model_fns(cfg)
     toks = jnp.asarray(np.arange(8, dtype=np.int32)[None, :])
-    pos = jnp.asarray(np.arange(8, dtype=np.int32)[None, :])
-    _, small2 = fns.forward_prefill_chunk(cfg, params, small, toks, pos)
-    cache2 = put_slot(cache, small2, axes, 1)
-    fp = np.asarray(cache2["full_pos"])
-    assert np.all(fp[[0, 2]] == -1), "neighbor rows disturbed"
-    assert list(fp[1][:8]) == list(range(8))
-    assert np.all(fp[1][8:] == -1)
-    k2, k0 = np.asarray(cache2["k"]), np.asarray(cache["k"])
-    assert np.allclose(k2[..., 0, :, :, :], k0[..., 0, :, :, :])
-    assert not np.allclose(k2[..., 1, :8, :, :], 0)
-    cache3 = release_slot(cache2, 1)
-    assert np.all(np.asarray(cache3["full_pos"]) == -1)
-    # K/V bytes intentionally stay — positions are the validity source
-    assert np.allclose(np.asarray(cache3["k"]), k2)
+    _, small2 = fns.forward_prefill_chunk(
+        cfg, params, small, toks, toks, policy=policy,
+        kv_len=jnp.asarray([8], jnp.int32),
+        block_table=jnp.asarray([[4, 0]], jnp.int32))
+    return cache, put_slot(cache, small2, axes, 1)
 
 
-def test_batchserver_alias_is_continuous():
-    from repro.serve.server import BatchServer
-    assert BatchServer is ContinuousBatchServer
+def _check_slot1_only(cache, cache2):
+    """Slot leaves: rows 0 and 2 untouched, row 1 written.  Pool
+    leaves: block 4 written, every other block untouched."""
+    lp = np.asarray(cache2["local_pos"])
+    assert np.all(lp[[0, 2]] == -1), "neighbor slots disturbed"
+    assert sorted(lp[1]) == list(range(8))
+    pp = np.asarray(cache2["pool_pos"])
+    assert list(pp[4]) == list(range(8))
+    assert np.all(np.delete(pp, 4, axis=0) == -1), "other blocks written"
+    lk2, lk0 = _arr(cache2["local_k"]), _arr(cache["local_k"])
+    assert np.array_equal(lk2[:, :, [0, 2]], lk0[:, :, [0, 2]])
+    assert np.any(lk2[:, :, 1] != 0)
+    gk2, gk0 = _arr(cache2["global_k"]), _arr(cache["global_k"])
+    assert np.array_equal(np.delete(gk2, 4, axis=1),
+                          np.delete(gk0, 4, axis=1))
+    assert np.any(gk2[:, 4] != 0)
+
+
+def test_slot_view_isolated(ring_setup):
+    """take_slot/put_slot over the paged cache (``paged_slot_axes``)
+    touch exactly one slot's slot-addressed leaves (the sliding-window
+    ring) and pass the pool leaves through whole: a chunk lands in its
+    slot's ring rows and in the pool block its table row names."""
+    cfg, params = ring_setup
+    cache, cache2 = _chunk_into_slot1(cfg, params)
+    _check_slot1_only(cache, cache2)
 
 
 def test_kv_cache_bytes_encdec_sizing():
@@ -271,45 +301,28 @@ def test_kv_cache_bytes_encdec_sizing():
 # ---------------------------------------------------------------------------
 # Slot lifecycle: reset → chunked prefill → release → re-admit, float + int8
 # ---------------------------------------------------------------------------
-from repro.core import quantize as qz  # noqa: E402
-
-
-def test_slot_view_isolated_int8(setup):
-    """The int8 cache (Int8KV pairs) honors the same slot-view contract:
-    one row written through a chunk, neighbors untouched, release
-    invalidates positions while the paired q/scale bytes stay."""
-    cfg, params = setup
+def test_slot_view_isolated_int8(ring_setup):
+    """The int8 paged cache (Int8KV pairs) honors the same slot-view
+    contract: one slot's ring rows and one pool block written through a
+    chunk, neighbors untouched, scales written with their values."""
+    cfg, params = ring_setup
     qparams = qz.quantize_model_params(params, qz.INT8)
-    axes = slot_batch_axes(cfg, 3, 12, qz.INT8)
-    cache = alloc_decode_cache(cfg, slots=3, capacity=12, policy=qz.INT8)
-    assert isinstance(cache["k"], qz.Int8KV)
-    fns = api.model_fns(cfg)
-    small = take_slot(cache, axes, 1)
-    toks = jnp.asarray(np.arange(8, dtype=np.int32)[None, :])
-    pos = jnp.asarray(np.arange(8, dtype=np.int32)[None, :])
-    _, small2 = fns.forward_prefill_chunk(cfg, qparams, small, toks, pos,
-                                          policy=qz.INT8)
-    assert isinstance(small2["k"], qz.Int8KV)
-    cache2 = put_slot(cache, small2, axes, 1)
-    fp = np.asarray(cache2["full_pos"])
-    assert np.all(fp[[0, 2]] == -1), "neighbor rows disturbed"
-    assert list(fp[1][:8]) == list(range(8))
-    q2, q0 = np.asarray(cache2["k"].q), np.asarray(cache["k"].q)
-    s2 = np.asarray(cache2["k"].scale)
-    assert np.array_equal(q2[..., 0, :, :, :], q0[..., 0, :, :, :])
-    assert not np.array_equal(q2[..., 1, :8, :, :],
-                              np.zeros_like(q2[..., 1, :8, :, :]))
-    assert np.all(s2[..., 1, :8, :] > 0), "scales not written with values"
-    cache3 = release_slot(cache2, 1)
-    assert np.all(np.asarray(cache3["full_pos"]) == -1)
-    assert np.array_equal(np.asarray(cache3["k"].q), q2)
+    cache, cache2 = _chunk_into_slot1(cfg, qparams, qz.INT8)
+    assert isinstance(cache2["local_k"], qz.Int8KV)
+    assert isinstance(cache2["global_k"], qz.Int8KV)
+    _check_slot1_only(cache, cache2)
+    ls = np.asarray(cache2["local_k"].scale)
+    gs = np.asarray(cache2["global_k"].scale)
+    assert np.all(ls[:, :, 1] > 0), "ring scales not written with values"
+    assert np.all(gs[:, 4] > 0), "pool scales not written with values"
 
 
 @pytest.mark.parametrize("precision", ["float", "int8"])
 def test_slot_reuse_after_release_exact(setup, precision):
     """A slot that went reset → chunked prefill → release must serve its
-    next request exactly: stale KV from the previous occupant (bytes are
-    kept, only positions are wiped) can never leak into attention."""
+    next request exactly: stale KV from the previous occupant (blocks
+    are handed on unscrubbed, fenced only by kv_len) can never leak into
+    attention."""
     cfg, params = setup
     if precision == "int8":
         import dataclasses
@@ -318,10 +331,11 @@ def test_slot_reuse_after_release_exact(setup, precision):
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in (6, 9, 4)]
     budgets = [4, 3, 5]
-    # one slot: every request reuses the same cache row sequentially
-    srv = ContinuousBatchServer(cfg, params, slots=1, max_prompt=16,
-                                prefill_chunk=4, max_new_tokens=8,
-                                precision=precision)
+    # one slot and a one-slot pool: every request reuses the same slot
+    # and the same physical blocks sequentially, with no scrub between
+    srv = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
+                           prefill_chunk=4, max_new_tokens=8,
+                           precision=precision)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     srv.run()
     if precision == "float":
@@ -331,9 +345,9 @@ def test_slot_reuse_after_release_exact(setup, precision):
         # fresh single-request int8 servers: no prior slot occupancy
         refs = []
         for p, b in zip(prompts, budgets):
-            one = ContinuousBatchServer(cfg, params, slots=1, max_prompt=16,
-                                        prefill_chunk=4, max_new_tokens=8,
-                                        precision="int8")
+            one = PagedBatchServer(cfg, params, slots=1, max_prompt=16,
+                                   prefill_chunk=4, max_new_tokens=8,
+                                   precision="int8")
             (r,) = one.submit([p], max_new_tokens=[b])
             one.run()
             refs.append(r.tokens)
@@ -380,8 +394,8 @@ def test_ring_prefill_quantizes_after_gather(ring_setup):
 
 @pytest.mark.parametrize("precision", ["float", "int8"])
 def test_ring_serving_token_exact(ring_setup, precision):
-    """Continuous serving on a local:global sliding-window arch — ring
-    caches filled by chunked scatter writes, ring-slot decode writes —
+    """Serving on a local:global sliding-window arch — ring caches
+    filled by chunked scatter writes, ring-slot decode writes —
     is token-exact vs the contiguous reference (float) or the fake-quant
     float simulation (int8)."""
     cfg, params = ring_setup
@@ -390,18 +404,18 @@ def test_ring_serving_token_exact(ring_setup, precision):
     budgets = [4, 6, 3]
     prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
                for n in lens]
-    srv = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                prefill_chunk=8, max_new_tokens=8,
-                                precision=precision)
+    srv = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                           prefill_chunk=8, max_new_tokens=8,
+                           precision=precision)
     reqs = srv.submit(prompts, max_new_tokens=budgets)
     srv.run()
     if precision == "float":
         refs = [_reference_decode(cfg, params, p, b)
                 for p, b in zip(prompts, budgets)]
     else:
-        fq = ContinuousBatchServer(cfg, params, slots=2, max_prompt=16,
-                                   prefill_chunk=8, max_new_tokens=8,
-                                   precision="int8_fakequant")
+        fq = PagedBatchServer(cfg, params, slots=2, max_prompt=16,
+                              prefill_chunk=8, max_new_tokens=8,
+                              precision="int8_fakequant")
         fq.submit(prompts, max_new_tokens=budgets)
         fq.run()
         refs = [r.tokens for r in fq.requests.values()]

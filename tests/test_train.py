@@ -182,10 +182,10 @@ def test_warmup_cosine_shape():
 
 
 def test_batch_server_generates(tiny_setup):
-    from repro.serve.server import BatchServer
+    from repro.serve.server import PagedBatchServer
     cfg, params, _ = tiny_setup
-    server = BatchServer(cfg, params, batch_size=2, prompt_len=8,
-                         max_new_tokens=4)
+    server = PagedBatchServer(cfg, params, slots=2, max_prompt=8,
+                              max_new_tokens=4)
     rng = np.random.RandomState(0)
     server.submit([rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
                    for _ in range(4)])
