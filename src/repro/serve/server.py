@@ -14,9 +14,11 @@ under a per-step token budget, each chunk written unpadded at positions
 ``[p, p + C)``.  Finished sequences release their slot *between decode
 steps* and waiting requests are admitted into freed slots; per-request
 ``max_new_tokens`` is honored in-step.  One chunk shape compiles once.
-Optionally the decode hot loop runs a ``CompiledArtifact``
-(``core/eon_compiler.compile_serve_decode``), so serving executes the
-same AOT executable we "deploy" (paper C4).
+Decode step n+1 is launched before step n's tokens are pulled, so the
+host's loop runs while the device runs a step (docs/serving.md,
+"One-step decode lookahead").  Optionally the decode hot loop runs a
+``CompiledArtifact`` (``core/eon_compiler.compile_serve_decode``), so
+serving executes the same AOT executable we "deploy" (paper C4).
 
 The engine accepts ``precision="float" | "int8"`` (paper C5 threaded
 end-to-end) and puts the weights in their at-rest form once at
@@ -284,6 +286,9 @@ class PagedBatchServer:
         else:
             self._reset = None
         self._cur = np.zeros((self.n_slots,), np.int32)
+        # the last decode step's token output, whose form (committed to
+        # its device or not) a host-built token operand takes
+        self._tok_out = None
         # host mirror of the device block-table operand (0 = unmapped:
         # always a valid physical block; dead entries are fenced by
         # kv_len, not by the table)
@@ -445,11 +450,13 @@ class PagedBatchServer:
                 req.admitted_step = decode_steps
                 req.admitted_at = time.perf_counter()
 
-    def _grow_for_decode(self, active) -> list:
+    def _grow_for_decode(self, active, preempt: bool):
         """Ensure every active slot owns the block this step's write
         lands in, preempting the youngest occupied slot (LIFO, vLLM-
         style) whenever the pool runs dry.  Oldest slots grow first, so
-        under pressure service order degenerates gracefully to FCFS."""
+        under pressure service order degenerates gracefully to FCFS.
+        With ``preempt`` False a dry pool returns None instead, having
+        preempted nothing (the blocks already grown stay)."""
         if not self.paged_keys:
             return active                   # pure-SSM: nothing paged
         for s in sorted(active, key=lambda x: x.rid):
@@ -460,11 +467,36 @@ class PagedBatchServer:
                     self.block_table[s.index,
                                      len(s.blocks) - 1] = s.blocks[-1]
                 except PoolExhausted:
+                    if not preempt:
+                        return None
                     victim = self.sched.preemption_victim()
                     self._preempt(victim)
                     if victim is s:
                         break
         return [s for s in active if s.active]
+
+    def _decode_ready(self) -> list:
+        """ACTIVE slots with a token still to come: a slot whose last
+        token a launched step already computes sits the next step out."""
+        return [s for s in self.sched.active_slots()
+                if s.generated < s.max_new]
+
+    def _grow(self, preempt: bool):
+        """``_grow_for_decode`` over the ready slots, in its span."""
+        with TraceAnnotation("serve.grow") as span:
+            before = self.preemptions
+            active = self._grow_for_decode(self._decode_ready(), preempt)
+            span.set_metadata(preempted=self.preemptions - before)
+        return active
+
+    def _host_tokens(self) -> jax.Array:
+        """The host token mirror as a decode token operand, in the form
+        the step gives its own token output: an ahead step passes that
+        output on as it is, so both key the one compiled program."""
+        out = self._tok_out
+        if out is not None and out.committed:
+            return jax.device_put(self._cur.copy(), out.sharding)
+        return jnp.asarray(self._cur.copy())
 
     def _run_chunk(self, slot, step_clock: int) -> None:
         """One prefill chunk for ``slot``; flips it ACTIVE (and emits the
@@ -482,10 +514,13 @@ class PagedBatchServer:
                 poss = np.full((1, c), -1, np.int32)
                 toks[0, :r] = prompt[p:p + r]
                 poss[0, :r] = np.arange(p, p + r, dtype=np.int32)
+                # a copy of the row: on the CPU an array made from numpy
+                # may share its memory, and the host table changes while
+                # the step is queued
                 args = (jnp.asarray(toks), jnp.asarray(poss), slot.index,
                         jnp.asarray([p + c], jnp.int32),
                         jnp.asarray(self.block_table[slot.index:
-                                                     slot.index + 1]))
+                                                     slot.index + 1].copy()))
             with TraceAnnotation("serve.chunk.launch"):
                 ntok, _, self.cache = self._chunk_step(self.params,
                                                        self.cache, *args)
@@ -512,21 +547,28 @@ class PagedBatchServer:
             else:
                 self._cur[slot.index] = tok0
 
-    def _emit(self, active, ntok, step_clock: int) -> None:
-        """Append each active slot's token from the pulled decode output
-        ``ntok``, stamped with one host time for the step, then finish
-        and free the slots whose request is done."""
+    def _pull(self, step) -> None:
+        """Pull a launched decode step's tokens and emit them: append
+        each token to its request, stamped with one host time for the
+        step, then finish and free the slots whose request is done.  A
+        slot whose request finished at the step before (its ``eos``
+        came after this step was launched) has moved on: its token is
+        dropped."""
+        launched, ntok, step_clock = step
+        with TraceAnnotation("serve.decode.pull"):
+            ntok = np.asarray(ntok)
         with TraceAnnotation("serve.decode.emit") as span:
             now = time.perf_counter()
             finished = 0
-            for s in active:
-                req = self.requests[s.rid]
+            for s, rid in launched:
+                if s.rid != rid:
+                    continue
+                req = self.requests[rid]
                 t = int(ntok[s.index])
                 req.tokens.append(t)
                 req.token_times.append(now)
-                s.advance()
                 self._cur[s.index] = t
-                if s.generated >= s.max_new or t == self.eos_id:
+                if len(req.tokens) >= s.max_new or t == self.eos_id:
                     self._finish(req, step_clock)
                     self._free_slot(s)
                     finished += 1
@@ -545,17 +587,24 @@ class PagedBatchServer:
         t0 = time.perf_counter()
         self._served: List[Request] = []
         decode_steps = 0
+        ahead_steps = 0
         prefill_chunks = 0
         occupancy: List[int] = []
         kv_fill: List[int] = []
         kv_raw: List[int] = []
         live_hist: List[int] = []
+        # the decode step launched and not yet pulled: ([(slot, rid)],
+        # its token output on the device, its decode-step clock)
+        pending = None
 
-        # Host spans on the profiler's clock (docs/serving.md,
-        # "Observability"): the loop is synchronous, so the host waits
-        # on the device only inside a ``*.pull`` span, and every device
-        # idle gap falls inside a named span.
-        while self.sched.busy:
+        # Decode step n+1 is launched before step n's tokens are pulled,
+        # its token operand step n's output on the device, so the host's
+        # work of an iteration runs while the device runs step n+1.  The
+        # loop drains (pulls step n first) only where it needs step n's
+        # tokens on the host: a slot enters decode, or growth must
+        # preempt (docs/serving.md, "One-step decode lookahead").  Host
+        # spans are on the profiler's clock.
+        while self.sched.busy or pending is not None:
             with TraceAnnotation("serve.iteration", step=decode_steps):
                 with TraceAnnotation("serve.admit") as span:
                     waiting = len(self.sched.waiting)
@@ -574,34 +623,57 @@ class PagedBatchServer:
                     if spent >= self.prefill_budget:
                         break
 
-                with TraceAnnotation("serve.grow") as span:
-                    preempted = self.preemptions
-                    active = self._grow_for_decode(self.sched.active_slots())
-                    span.set_metadata(preempted=self.preemptions - preempted)
-                if not active:
-                    continue
+                if pending is not None:
+                    inflight = {(s.index, rid) for s, rid in pending[0]}
+                    if any((s.index, s.rid) not in inflight
+                           for s in self._decode_ready()):
+                        # a slot entered decode: its token is on the host
+                        self._pull(pending)
+                        pending = None
+                active = self._grow(preempt=pending is None)
+                if active is None:
+                    # the pool is dry, and a preempted request is
+                    # re-prefilled over prompt ++ generated: step n's
+                    # tokens first
+                    self._pull(pending)
+                    pending = None
+                    active = self._grow(preempt=True)
 
-                with TraceAnnotation("serve.decode.prepare",
-                                     active=len(active)):
-                    tok = np.array(self._cur)
-                    pos = np.zeros((self.n_slots,), np.int32)
-                    kvl = np.zeros((self.n_slots,), np.int32)
+                step = None
+                if active:
+                    ahead = pending is not None
+                    with TraceAnnotation("serve.decode.prepare",
+                                         active=len(active)):
+                        # every slot of an ahead step was in step n, so
+                        # step n's output is its token operand as it is
+                        tok = pending[1] if ahead else self._host_tokens()
+                        pos = np.zeros((self.n_slots,), np.int32)
+                        kvl = np.zeros((self.n_slots,), np.int32)
+                        for s in active:
+                            pos[s.index] = s.position
+                            kvl[s.index] = s.position + 1
+                        # a copy: the host table changes while the step
+                        # is queued (see ``_run_chunk``)
+                        table = jnp.asarray(self.block_table.copy())
+                    with TraceAnnotation("serve.decode.launch",
+                                         ahead=int(ahead)):
+                        ntok, _, self.cache = self.decode(
+                            self.params, self.cache, tok, pos, kvl, table)
+                    self._tok_out = ntok
                     for s in active:
-                        pos[s.index] = s.position
-                        kvl[s.index] = s.position + 1
-                    table = jnp.asarray(self.block_table)
-                with TraceAnnotation("serve.decode.launch"):
-                    ntok, _, self.cache = self.decode(
-                        self.params, self.cache, tok, pos, kvl, table)
-                decode_steps += 1
-                occupancy.append(len(active))
-                live_hist.append(self.manager.live_blocks)
-                blocks = np.maximum(-(-kvl // self.block_size), 1)
-                kv_fill.append(int(blocks.sum()) * self.block_size)
-                kv_raw.append(int(kvl.sum()))
-                with TraceAnnotation("serve.decode.pull"):
-                    ntok_h = np.asarray(ntok)
-                self._emit(active, ntok_h, decode_steps)
+                        s.advance()
+                    decode_steps += 1
+                    ahead_steps += ahead
+                    occupancy.append(len(active))
+                    live_hist.append(self.manager.live_blocks)
+                    blocks = np.maximum(-(-kvl // self.block_size), 1)
+                    kv_fill.append(int(blocks.sum()) * self.block_size)
+                    kv_raw.append(int(kvl.sum()))
+                    step = ([(s, s.rid) for s in active], ntok,
+                            decode_steps)
+                if pending is not None:
+                    self._pull(pending)
+                pending = step
 
         served = self._served
         wall = time.perf_counter() - t0
@@ -609,6 +681,9 @@ class PagedBatchServer:
                                   prefills=prefill_chunks,
                                   occupancy=occupancy,
                                   n_slots=self.n_slots)
+        # decode steps launched while the step before was still unpulled
+        self.metrics["decode_ahead_share"] = (
+            ahead_steps / decode_steps if decode_steps else 0.0)
         self.metrics["precision"] = self.precision
         self.metrics["prefill_chunk"] = self.chunk
         self.metrics["kv_cache_bytes"] = decode_cache_nbytes(self.cache)

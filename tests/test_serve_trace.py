@@ -4,12 +4,15 @@
 The paged server runs under ``jax.profiler.trace`` and the trace is read
 back with ``ProfileData``: every ``serve.*`` span is there, nested as
 the loop nests them, with the request ids and counts it carries; one
-``serve.decode.launch`` per decode step and one ``serve.chunk`` per
-prefill chunk.  The request stamps hold in the same runs: admission
-after submission, one non-decreasing host time per emitted token, the
-first of them the first-token time.  Each check runs on a plain
-workload and on one whose pool is smaller than the slots' rectangle,
-so that preempt-and-recompute re-admits and re-prefills requests.
+``serve.decode.launch`` and one ``serve.decode.pull`` per decode step
+and one ``serve.chunk`` per prefill chunk.  A launch may come before
+the pull of the step before it: its ``ahead`` flag says so, and a
+preemption never has a step in flight.  The request stamps hold in the
+same runs: admission after submission, one non-decreasing host time per
+emitted token, the first of them the first-token time.  Each check runs
+on a plain workload and on one whose pool is smaller than the slots'
+rectangle, so that preempt-and-recompute re-admits and re-prefills
+requests.
 """
 import dataclasses
 
@@ -136,6 +139,31 @@ def test_span_counts_match_the_counters(traced):
     assert total("serve.grow", "preempted") == m["preemptions"]
     # every budget is above one token: each request ends at a decode step
     assert total("serve.decode.emit", "finished") == len(reqs)
+
+
+def test_launches_carry_ahead(traced):
+    """Each decode launch says whether the step before it was still
+    unpulled; the flags sum to the server's share times its steps, and
+    both kinds of launch occur."""
+    _, m, spans = traced
+    ahead = [s[3]["ahead"] for s in spans if s[0] == "serve.decode.launch"]
+    assert set(ahead) == {0, 1}
+    assert sum(ahead) == round(m["decode_ahead_share"] * m["decode_steps"])
+
+
+def test_preemption_waits_for_the_step_in_flight(traced):
+    """Growth that has to preempt comes after the pull of every decode
+    step launched before it: a preempted request is re-prefilled over
+    prompt ++ generated, which then holds its last token."""
+    _, m, spans = traced
+    grows = [s for s in spans if s[0] == "serve.grow" and s[3]["preempted"]]
+    assert sum(g[3]["preempted"] for g in grows) == m["preemptions"]
+    for g in grows:
+        launched = sum(1 for s in spans
+                       if s[0] == "serve.decode.launch" and s[1] < g[1])
+        pulled = sum(1 for s in spans
+                     if s[0] == "serve.decode.pull" and s[2] <= g[1])
+        assert launched == pulled, (launched, pulled)
 
 
 def test_request_stamps(traced):
